@@ -109,7 +109,11 @@ from common import emit  # noqa: E402
 
 from repro.audio.synthetic import batch_for_step  # noqa: E402
 from repro.core.quant import FP10  # noqa: E402
-from repro.launch.serve import parse_tiers, reduced_cfg  # noqa: E402
+from repro.launch.serve import (  # noqa: E402
+    enable_compile_cache,
+    parse_tiers,
+    reduced_cfg,
+)
 from repro.models import tftnn as tft  # noqa: E402
 from repro.serve import (  # noqa: E402
     DurabilityManager,
@@ -317,15 +321,16 @@ def run_ramp(params, cfg, tiers: tuple, audio: np.ndarray, quant,
     fed, so a session dropped or corrupted by a resize fails the run instead
     of vanishing into an average. ``shrink_patience=1`` makes the down-ramp
     shrink on the next pump instead of waiting out the serving-loop
-    hysteresis; ``prewarm=True`` compiles every tier up front so per-tier RTF
+    hysteresis; ``prewarm()`` compiles every tier up front so per-tier RTF
     measures serving, not jit.
     """
     pool = ElasticSessionPool(
         params, cfg, tiers, quant=quant, backend=backend,
         inflight=2 if buffering == "double" else 1,
         hops_per_step=hops_per_step, step_fn=step_fn,
-        shrink_patience=1, prewarm=True,
+        shrink_patience=1,
     )
+    pool.prewarm()
     hop, sr = cfg.hop, pool.sample_rate
     pilot = pool.attach()
     handles = []
@@ -541,6 +546,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_server_throughput.json",
                     help="where to write the machine-readable results")
     args = ap.parse_args()
+    enable_compile_cache()
 
     backends = _csv_list(args.backend, ("xla", "pallas"))
     bufferings = _csv_list(args.buffering, ("single", "double"))

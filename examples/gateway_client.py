@@ -12,38 +12,49 @@ speaking the framed streaming protocol:
 - one client's connection is severed without detaching; a new connection
   re-attaches the same session id and resumes with nothing lost.
 
-At the end, every stream is verified bit-identical to a solo in-process
-pool that never saw a network or a failure, and the gateway's failover
-metrics are printed.
+At the end, every stream is verified against a solo in-process pool that
+never saw a network or a failure — bit-identical for the in-thread gateway,
+within ``CONNECT_MIN_SI_SNR_DB`` for an external one — and the gateway's
+failover metrics are printed.
 
 Run:  PYTHONPATH=src python examples/gateway_client.py
 Or serve a standalone gateway and connect from another terminal/process:
 
   PYTHONPATH=src python -m repro.launch.serve --task gateway --reduced --port 7861
   PYTHONPATH=src python examples/gateway_client.py --connect 127.0.0.1:7861
+
+With ``--connect`` this process keeps JAX on the CPU: the gateway process
+owns the accelerator, and a chip belongs to one process at a time.
 """
 
 import argparse
-import dataclasses
 
 import jax
 import numpy as np
-
-from repro.audio.synthetic import batch_for_step
-from repro.models import tftnn as tft
-from repro.serve import SessionPool, ShardedSessionPool
-from repro.serve.gateway import GatewayClient, GatewayThread
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--connect", default="",
                 help="host:port of a running --task gateway; default spins "
                 "up an in-thread gateway (and can then inject failures)")
 args = ap.parse_args()
+if args.connect:
+    jax.config.update("jax_platforms", "cpu")  # before JAX picks a device
 
-cfg = dataclasses.replace(
-    tft.tftnn_config(), freq_bins=64, channels=16, att_dim=8, num_heads=1,
-    gru_hidden=16, dilation_rates=(1, 2, 4),
-)
+from repro.audio.metrics import si_snr_db  # noqa: E402
+from repro.audio.synthetic import batch_for_step  # noqa: E402
+from repro.launch.serve import reduced_cfg  # noqa: E402
+from repro.models import tftnn as tft  # noqa: E402
+from repro.serve import SessionPool, ShardedSessionPool  # noqa: E402
+from repro.serve.gateway import GatewayClient, GatewayThread  # noqa: E402
+
+# An external gateway computes on its own device, possibly a TPU, where an
+# f32 matmul defaults to one bf16 pass; this CPU reference runs full f32.
+# Only that arithmetic may separate the two; a lost or repeated hop costs
+# tens of dB.
+CONNECT_MIN_SI_SNR_DB = 20.0
+
+# the launcher's --reduced trunk with its PRNGKey(0) weights
+cfg = reduced_cfg(tft.tftnn_config())
 params = tft.init_tft(jax.random.PRNGKey(0), cfg)
 hop = cfg.hop
 
@@ -106,9 +117,16 @@ for i, (name, got) in enumerate([("alice", out_alice), ("bob", out_bob)]):
     solo.feed(s, audio[i])
     solo.pump()
     want = solo.detach(s)[:n_out]
-    match = np.array_equal(got, want)
-    print(f"{name}: {got.size} samples over TCP, bit-identical to "
-          f"in-process: {match}")
+    if gw is not None:
+        match = np.array_equal(got, want)
+        print(f"{name}: {got.size} samples over TCP, bit-identical to "
+              f"in-process: {match}")
+    else:
+        db = float(si_snr_db(got, want))
+        match = db >= CONNECT_MIN_SI_SNR_DB
+        print(f"{name}: {got.size} samples over TCP, SI-SNR vs in-process "
+              f"CPU reference {db:.1f} dB (>= {CONNECT_MIN_SI_SNR_DB} dB: "
+              f"{match})")
     assert match, f"{name}'s stream diverged crossing the fabric"
 
 if gw is not None:

@@ -13,16 +13,45 @@ puts the sharded fleet behind the cross-process socket front door
 (``--port``, health-checked shards with ticket failover — point
 ``examples/gateway_client.py --connect`` at it); ``--task lm`` runs
 batched greedy decode on a reduced arch. See docs/serving.md.
+
+Every pool task compiles all of its step shapes before serving, and JAX's
+persistent compile cache lives in ``$JAX_COMPILATION_CACHE_DIR`` when that is
+set, else in ``.jax_cache/`` at the root of the checkout.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, so
+    nothing is set here. Otherwise the cache goes to ``.jax_cache`` at the
+    root of the checkout: a fixed path, because the path is part of what a
+    later run must find again. Call before the first compile.
+    """
+    if os.environ.get(CACHE_ENV):
+        return os.environ[CACHE_ENV]
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_label() -> str:
+    """What JAX runs on, e.g. ``tpu/TPU v5 lite x1``."""
+    devs = jax.devices()
+    return f"{devs[0].platform}/{devs[0].device_kind} x{len(devs)}"
 
 
 def reduced_cfg(cfg):
@@ -33,17 +62,24 @@ def reduced_cfg(cfg):
                                num_heads=1, gru_hidden=16, dilation_rates=(1, 2, 4))
 
 
-def serve_se(args) -> None:
-    from repro.audio.metrics import all_metrics
-    from repro.audio.synthetic import batch_for_step
+def model(args):
+    """``(cfg, params)``: the paper's TFTNN (``--reduced``: the CPU-demo
+    trunk) with fresh weights from PRNG key 0 — the repo ships none."""
     from repro.models import tftnn as tft
-    from repro.serve.streaming_se import init_stream, stream_hop
-    from repro.train.checkpoint import Checkpointer
 
     cfg = tft.tftnn_config()
     if args.reduced:
         cfg = reduced_cfg(cfg)
-    params = tft.init_tft(jax.random.PRNGKey(0), cfg)
+    return cfg, tft.init_tft(jax.random.PRNGKey(0), cfg)
+
+
+def serve_se(args) -> None:
+    from repro.audio.metrics import all_metrics
+    from repro.audio.synthetic import batch_for_step
+    from repro.serve.streaming_se import init_stream, stream_hop
+    from repro.train.checkpoint import Checkpointer
+
+    cfg, params = model(args)
     if args.ckpt_dir:
         try:
             _, state = Checkpointer(args.ckpt_dir).restore(
@@ -57,6 +93,7 @@ def serve_se(args) -> None:
     state = init_stream(params, cfg, args.batch)
     hop = cfg.hop
     step = jax.jit(lambda s, x: stream_hop(params, cfg, s, x))
+    jax.block_until_ready(step(state, noisy[:, :hop]))  # compile, untimed
     outs, times = [], []
     n = args.samples // hop
     for i in range(n):
@@ -71,7 +108,7 @@ def serve_se(args) -> None:
     p50, p99 = times[len(times) // 2], times[int(len(times) * 0.99)]
     budget = hop / 8000.0
     print(f"hops={n} p50={p50 * 1e3:.2f}ms p99={p99 * 1e3:.2f}ms budget={budget * 1e3:.1f}ms "
-          f"real-time={'YES' if p99 < budget else 'no (CPU host; ASIC/TPU target)'}")
+          f"real-time={'YES' if p99 < budget else 'no'} on {device_label()}")
     scores = {k: round(float(v), 3) for k, v in all_metrics(est, clean[:, : est.shape[1]]).items()}
     print(f"quality vs clean: {scores}")
 
@@ -154,13 +191,9 @@ def serve_pool(args) -> None:
     SessionPool (or an ElasticSessionPool tier ladder with --elastic)."""
     from repro.audio.synthetic import batch_for_step
     from repro.core.quant import FP10
-    from repro.models import tftnn as tft
     from repro.serve import ElasticSessionPool, SessionPool
 
-    cfg = tft.tftnn_config()
-    if args.reduced:
-        cfg = reduced_cfg(cfg)
-    params = tft.init_tft(jax.random.PRNGKey(0), cfg)
+    cfg, params = model(args)
     kmax, sched, extra = adaptive_setup(args)
     extra.update(durability_setup(args))
     extra.update(guard_kw(args))
@@ -177,6 +210,7 @@ def serve_pool(args) -> None:
                            backend=args.backend, **prune_kw(args),
                            inflight=2 if args.double_buffer else 1,
                            hops_per_step=kmax, **extra)
+    pool.prewarm(sched.config.k_ladder if sched is not None else None)
     noisy, _ = batch_for_step(1, 0, batch=args.batch, num_samples=args.samples)
     audio = jnp.asarray(noisy)
     sessions = [pool.attach() for _ in range(args.batch)]
@@ -190,33 +224,46 @@ def serve_pool(args) -> None:
         pool.detach(s)
 
 
-def serve_sharded(args) -> None:
-    """Sharded server: --shards SessionPools behind the consistent-hash router."""
-    from repro.audio.synthetic import batch_for_step
+def build_sharded_pool(args, params, cfg, devices=None):
+    """The ``ShardedSessionPool`` the sharded and gateway tasks serve from,
+    built from the launcher's parsed arguments, with every step shape
+    already compiled (``prewarm``).
+
+    ``devices`` defaults to every local device. Each shard gets
+    ``ceil(batch / shards)`` slots, at least 2 (XLA specializes batch-1
+    steps). A compile error raises here, before any session exists.
+    """
     from repro.core.quant import FP10
-    from repro.models import tftnn as tft
     from repro.serve import ShardedSessionPool
 
-    cfg = tft.tftnn_config()
-    if args.reduced:
-        cfg = reduced_cfg(cfg)
-    params = tft.init_tft(jax.random.PRNGKey(0), cfg)
-    n_dev = len(jax.local_devices())
-    per_shard = max(1, -(-args.batch // args.shards))  # ceil; hash skew absorbed below
+    per_shard = max(2, -(-args.batch // args.shards))
     tiers = parse_tiers(args.tiers) if args.elastic else None
     kmax, _, extra = adaptive_setup(args)
     extra.update(durability_setup(args))
     extra.update(guard_kw(args))
     extra.update(breaker_kw(args))
     pool = ShardedSessionPool(params, cfg, per_shard, shards=args.shards,
+                              devices=devices,
                               quant=FP10 if args.quant else None,
                               backend=args.backend, **prune_kw(args),
                               inflight=2 if args.double_buffer else 1,
                               hops_per_step=kmax,
                               tiers=tiers, adaptive=args.adaptive or None,
                               **extra)
-    slots = f"tiers {tiers}" if args.elastic else f"{per_shard} slots"
-    print(f"{args.shards} shards x {slots} over {n_dev} local device(s)"
+    pool.prewarm()
+    return pool
+
+
+def serve_sharded(args) -> None:
+    """Sharded server: --shards SessionPools behind the consistent-hash router."""
+    from repro.audio.synthetic import batch_for_step
+
+    cfg, params = model(args)
+    pool = build_sharded_pool(args, params, cfg)
+    slots = (f"tiers {parse_tiers(args.tiers)}" if args.elastic
+             else f"{pool.capacity // args.shards} slots")
+    print(f"{args.shards} shards x {slots} over {len(jax.local_devices())} "
+          f"local device(s) [{device_label()}]"
           + (" [adaptive]" if args.adaptive else ""))
     noisy, _ = batch_for_step(1, 0, batch=args.batch, num_samples=args.samples)
     audio = jnp.asarray(noisy)
@@ -242,35 +289,18 @@ def serve_gateway(args) -> None:
     """
     import asyncio
 
-    from repro.core.quant import FP10
-    from repro.models import tftnn as tft
-    from repro.serve import ShardedSessionPool
     from repro.serve.gateway import StreamingGateway
 
-    cfg = tft.tftnn_config()
-    if args.reduced:
-        cfg = reduced_cfg(cfg)
-    params = tft.init_tft(jax.random.PRNGKey(0), cfg)
-    per_shard = max(2, -(-args.batch // args.shards))
-    tiers = parse_tiers(args.tiers) if args.elastic else None
-    kmax, _, extra = adaptive_setup(args)
-    extra.update(durability_setup(args))
-    extra.update(guard_kw(args))
-    extra.update(breaker_kw(args))
-    pool = ShardedSessionPool(params, cfg, per_shard, shards=args.shards,
-                              quant=FP10 if args.quant else None,
-                              backend=args.backend, **prune_kw(args),
-                              inflight=2 if args.double_buffer else 1,
-                              hops_per_step=kmax,
-                              tiers=tiers, adaptive=args.adaptive or None,
-                              **extra)
+    cfg, params = model(args)
+    pool = build_sharded_pool(args, params, cfg)
     gateway = StreamingGateway(pool, host=args.host, port=args.port)
 
     async def _serve() -> None:
         await gateway.start()
         host, port = gateway.address
         print(f"gateway listening on {host}:{port} "
-              f"({args.shards} shards, {pool.capacity} slots); Ctrl-C stops")
+              f"({args.shards} shards, {pool.capacity} slots, "
+              f"{device_label()}); Ctrl-C stops")
         try:
             await asyncio.Event().wait()
         finally:
@@ -298,7 +328,9 @@ def serve_lm(args) -> None:
           f"({args.batch * args.tokens / dt:.1f} tok/s); sample: {out.tokens[0][:16].tolist()}")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (also parsed by ``chip_smoke.py``, so the
+    smoke builds its pools from the same flags a user passes)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--task", choices=["se", "pool", "sharded", "gateway", "lm"],
                     default="se")
@@ -380,7 +412,12 @@ def main() -> None:
     ap.add_argument("--samples", type=int, default=16000)
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--ckpt-dir", default="")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
     {"se": serve_se, "pool": serve_pool, "sharded": serve_sharded,
      "gateway": serve_gateway, "lm": serve_lm}[args.task](args)
 

@@ -13,7 +13,7 @@ sessions migrated **bit-exactly** between tiers through the existing
 - **One step function, one compilation per tier** — all tiers share a single
   ``make_stream_hop`` callable; jax.jit specializes it per batch shape, so
   tier capacity N compiles exactly once (the first step at that tier, or
-  eagerly with ``prewarm=True``). Resizing swaps the *state*, never the code.
+  eagerly in ``prewarm()``). Resizing swaps the *state*, never the code.
 - **Grow on attach-would-overflow** — ``attach()`` on a full pool climbs to
   the next tier instead of raising; ``PoolFullError`` only at the top tier.
 - **Shrink on sustained low occupancy** — every ``pump()``/``step()`` ticks
@@ -64,7 +64,6 @@ from repro.serve.session_server import (
     SessionPool,
     SessionTicket,
 )
-from repro.serve.streaming_se import init_stream
 
 Pytree = dict
 
@@ -122,10 +121,6 @@ class ElasticSessionPool:
         shrink_patience: consecutive eligible ``pump()``/``step()`` checks
             required before a shrink actually happens (default 8). Growth
             has no patience — an attach must not fail while capacity exists.
-        prewarm: compile (and time) every tier's step at construction by
-            running one masked-out step per tier, so no serving-path step
-            ever pays a jit compile. Off by default (tests construct many
-            pools); the ramp benchmark turns it on.
         step_fn: pre-built hop step shared with other pools (see
             ``SessionPool``); seeds the default lane-count entry of the
             shared step cache when given.
@@ -175,7 +170,6 @@ class ElasticSessionPool:
         hops_per_step: int = 1,
         shrink_fraction: float = 0.5,
         shrink_patience: int = 8,
-        prewarm: bool = False,
         step_fn=None,
         step_fns: Optional[Dict[Any, Any]] = None,
         ingest_ring: Optional[int] = None,
@@ -258,8 +252,6 @@ class ElasticSessionPool:
         self.shrink_count = 0
         self.resize_seconds: List[float] = []  # pause per resize (migration)
         self.resize_log: List[Tuple[int, int]] = []  # (from_cap, to_cap)
-        if prewarm:
-            self._prewarm()
 
     def _wake(self, on_unparked, inner: Session) -> None:
         for handle in self._handles.values():
@@ -293,32 +285,14 @@ class ElasticSessionPool:
             fault_tag=self._fault_tag,
         )
 
-    def _prewarm(self) -> None:
-        """Compile every tier's batch shape now (one masked-out step each),
-        so a serving-path resize never stalls on jit."""
-        hop, K, R = self.cfg.hop, self.hops_per_step, self._ingest_ring
-        step = self._pool._step_for(K)
+    def prewarm(self, lane_counts: Optional[Sequence[int]] = None) -> None:
+        """Compile every tier's programs now (``SessionPool.prewarm`` on a
+        pool of each tier's capacity; ``lane_counts`` default to
+        ``(hops_per_step,)``), so neither a serving-path dispatch nor a
+        resize stalls on jit."""
         for cap in self.tiers:
-            state = init_stream(self._params, self.cfg, cap)
-            lanes = (
-                np.zeros((cap,), bool) if K == 1 else np.zeros((cap,), np.int32)
-            )
-            if R is not None:  # ring form: gather lanes from the device ring
-                inputs = (
-                    np.zeros((cap, R, hop), np.float32),
-                    np.zeros((cap,), np.int32),
-                    lanes,
-                )
-            elif K == 1:
-                inputs = (np.zeros((cap, hop), np.float32), lanes)
-            else:  # fused step: packed lanes + per-slot hop counts
-                inputs = (np.zeros((cap, K, hop), np.float32), lanes)
-            if self.device is not None:
-                state = jax.device_put(state, self.device)
-                inputs = tuple(jax.device_put(x, self.device) for x in inputs)
-            new_state, out = step(state, *inputs)
-            jax.block_until_ready(out)
-            del new_state  # donated dummy state; the live pool keeps its own
+            pool = self._pool if cap == self._pool.capacity else self._make_pool(cap)
+            pool.prewarm(lane_counts)
 
     # -- capacity / introspection -------------------------------------------
 

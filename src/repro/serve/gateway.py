@@ -60,6 +60,7 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import json
+import logging
 import random
 import socket
 import struct
@@ -97,6 +98,8 @@ _HEADER = struct.Struct("<IB")
 _BUSY_HEAD = struct.Struct("<I")
 # one frame must hold minutes of fp32 audio but never an accidental gigabyte
 MAX_FRAME_BYTES = 64 * 1024 * 1024
+
+log = logging.getLogger(__name__)
 
 
 class ProtocolError(RuntimeError):
@@ -183,6 +186,13 @@ class StreamingGateway:
         self.sessions_poisoned = 0  # MSG_POISONED frames sent
         self._server: Optional[asyncio.AbstractServer] = None
         self._pump_task: Optional[asyncio.Task] = None
+        # set when the heartbeat raised: every later request gets it as a
+        # typed ERROR (clients fail now, not at their deadline) and stop()
+        # re-raises it
+        self._pump_error: Optional[BaseException] = None
+        # open connections: Python >= 3.12's Server.wait_closed() waits for
+        # every one of them, so stop() has to close them itself
+        self._connections: Dict[asyncio.StreamWriter, asyncio.Task] = {}
         # session id -> live pool handle, for every gateway-attached session
         self._handles: Dict[str, object] = {}
         # session id -> ticks since its connection dropped (un-detached)
@@ -228,23 +238,53 @@ class StreamingGateway:
             self.sessions_recovered_at_start += 1
 
     async def stop(self) -> None:
-        """Stop serving: close the listener, cancel the pump loop."""
+        """Stop serving: cancel the pump loop, close the listener and every
+        open connection.
+
+        Raises:
+            Exception: whatever killed the pump loop, after the shutdown
+                completed (a gateway whose heartbeat died must not look
+                like one that served cleanly).
+        """
         if self._pump_task is not None:
             self._pump_task.cancel()
             try:
                 await self._pump_task
             except asyncio.CancelledError:
                 pass
+            except Exception:
+                pass  # kept in _pump_error by _tick; raised below
             self._pump_task = None
         if self._server is not None:
             self._server.close()
+            for writer in list(self._connections):
+                writer.close()
+            handlers = list(self._connections.values())
+            if handlers:
+                await asyncio.wait(handlers)
             await self._server.wait_closed()
             self._server = None
+        if self._pump_error is not None:
+            raise self._pump_error
 
     # -- the serving heartbeat ---------------------------------------------
 
     def _tick(self) -> None:
-        """One heartbeat: health-probe shards, pump, reap expired orphans."""
+        """One heartbeat: health-probe shards, pump, reap expired orphans.
+
+        A heartbeat that raises kills the gateway: the error is logged once
+        and kept, every later request is answered with it, and ``stop()``
+        re-raises it.
+        """
+        try:
+            self._beat()
+        except Exception as e:
+            if self._pump_error is None:
+                log.exception("gateway heartbeat failed; failing every request")
+                self._pump_error = e
+            raise
+
+    def _beat(self) -> None:
         check = getattr(self.pool, "check_shards", None)
         if check is not None:
             check()
@@ -291,6 +331,7 @@ class StreamingGateway:
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
         self.connections_served += 1
+        self._connections[writer] = asyncio.current_task()
         sid: Optional[str] = None
         try:
             while True:
@@ -341,7 +382,10 @@ class StreamingGateway:
                         # so this very connection can ATTACH a fresh stream
                     writer.write(_frame(MSG_ERROR, str(e).encode("utf-8")))
                 await writer.drain()
+        except ConnectionError:
+            pass  # client vanished mid-reply, or stop() closed the socket
         finally:
+            self._connections.pop(writer, None)
             if sid is not None and sid in self._handles:
                 self._orphans[sid] = 0  # keeps streaming until re-attach/TTL
             writer.close()
@@ -354,6 +398,11 @@ class StreamingGateway:
         self, msg_type: int, payload: bytes, sid: Optional[str]
     ) -> Tuple[int, bytes, Optional[str]]:
         """Handle one frame; returns (reply type, reply payload, new sid)."""
+        if self._pump_error is not None:
+            raise SessionError(
+                f"gateway pump loop died: {self._pump_error!r}; the gateway "
+                "is not serving"
+            )
         if msg_type == MSG_ATTACH:
             if sid is not None:
                 raise SessionError(
@@ -428,7 +477,10 @@ class StreamingGateway:
                           np.frombuffer(payload, np.float32))
             # opportunistic pump: a whole queued hop is served NOW instead
             # of waiting out the heartbeat interval
-            self._tick()
+            try:
+                self._tick()
+            except Exception as e:
+                raise SessionError(f"gateway heartbeat failed: {e!r}") from e
             return MSG_AUDIO, b"", sid
         if msg_type == MSG_READ:
             read_degraded = getattr(self.pool, "read_degraded", None)
@@ -558,6 +610,14 @@ class GatewayThread:
         return fn(self.gateway.pool)
 
     def stop(self) -> None:
+        """Shut the gateway down and join its thread.
+
+        Raises:
+            TimeoutError: the shutdown or the join outlived ``call_timeout``.
+            Exception: what killed the gateway's heartbeat, if anything did
+                (re-raised from ``StreamingGateway.stop`` after the thread
+                has been joined).
+        """
         if not self._thread.is_alive():
             return
         fut = asyncio.run_coroutine_threadsafe(self.gateway.stop(), self._loop)
@@ -569,6 +629,12 @@ class GatewayThread:
                 f"gateway stop() still pending after {self.call_timeout}s — "
                 "the event loop is wedged mid-shutdown"
             ) from exc
+        except Exception:
+            self._join()  # the shutdown completed; only the heartbeat failed
+            raise
+        self._join()
+
+    def _join(self) -> None:
         self._loop.call_soon_threadsafe(self._loop.stop)
         self._thread.join(timeout=self.call_timeout)
         if self._thread.is_alive():

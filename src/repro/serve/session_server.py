@@ -51,7 +51,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +122,30 @@ def _ring_write(ring, slot, start, block, n):
     live = (jnp.arange(R) < n)[:, None]
     cur = ring[slot][idx]
     return ring.at[slot, idx].set(jnp.where(live, block, cur))
+
+
+def warm_inputs(cfg: tft_mod.TFTConfig, capacity: int, k: int,
+                ring_depth: Optional[int]) -> Tuple[np.ndarray, ...]:
+    """Host inputs (after the state) for one all-masked-out dispatch of the
+    ``max_hops=k`` step at ``capacity`` slots: what ``prewarm`` runs so jit
+    compiles before a session needs the step.
+
+    The shapes and dtypes are exactly those ``SessionPool.dispatch`` ships:
+    the device-ring form takes ``(ring, starts, lanes)``, the staged form
+    ``(hops, lanes)``; lanes are a bool mask at k=1, int hop counts above.
+    """
+    lanes = (
+        np.zeros((capacity,), bool) if k == 1
+        else np.zeros((capacity,), np.int32)
+    )
+    if ring_depth is not None:
+        return (
+            np.zeros((capacity, ring_depth, cfg.hop), np.float32),
+            np.zeros((capacity,), np.int32),
+            lanes,
+        )
+    shape = (capacity, cfg.hop) if k == 1 else (capacity, k, cfg.hop)
+    return np.zeros(shape, np.float32), lanes
 
 
 class SessionError(RuntimeError):
@@ -593,6 +617,37 @@ class SessionPool:
             )
             self._steps[key] = step
         return step
+
+    def prewarm(self, lane_counts: Optional[Sequence[int]] = None) -> None:
+        """Compile now every program this pool runs while serving.
+
+        Runs each step once on a dummy state with every slot masked out —
+        the live state is untouched — so no ``dispatch()`` pays a jit
+        compile, and a compile error raises here instead of surfacing as a
+        failed step. Also compiles the attach-time slot reset, and the
+        finite guard's verdict and the ingest ring's write when the pool
+        uses them.
+
+        Args:
+            lane_counts: the ``max_hops`` values to compile; default
+                ``(hops_per_step,)``, the only one a pool dispatches without
+                an adaptive scheduler.
+        """
+        cap = self.capacity
+        put = lambda x: jax.device_put(x, self.device)  # noqa: E731
+        for k in lane_counts or (self.hops_per_step,):
+            state = reset_slots(
+                put(init_stream(self._params, self.cfg, cap)),
+                jnp.zeros((cap,), bool).at[0].set(True),
+            )
+            inputs = warm_inputs(self.cfg, cap, k, self._ring_depth)
+            state, out = self._step_for(k)(state, *(put(x) for x in inputs))
+            if self._finite_guard:
+                out = _finite_slots(state, out)
+            jax.block_until_ready(out)
+        if self._ring_depth is not None:
+            block = np.zeros((self._ring_depth, self.cfg.hop), np.float32)
+            jax.block_until_ready(_ring_write(self._ring_arr, 0, 0, block, 0))
 
     # -- session lifecycle --------------------------------------------------
 
@@ -1332,7 +1387,8 @@ class SessionPool:
             ``hops`` (total hops enhanced for currently-live sessions),
             ``backlog_hops`` (full hops queued but not yet processed —
             the pressure signal), ``p50_ms`` (median dispatch→ready step
-            latency), and ``device`` (where this shard's state lives).
+            latency), ``p99_ms``, and ``device`` (where this shard's state
+            lives).
             Pruned pools additionally report ``prune``: requested keep,
             exact realized sparsity, and the masked-MAC skip-rate counters
             per masked weight.
@@ -1342,13 +1398,15 @@ class SessionPool:
             for slot, s in enumerate(self._slot_session)
             if s is not None
         )
+        pct = self.latency_percentiles((50, 99))
         stats: Dict[str, object] = {
             "capacity": self.capacity,
             "active": self.num_active,
             "free": self.capacity - self.num_active,
             "hops": sum(s.stats.hops for s in self._sessions.values()),
             "backlog_hops": backlog,
-            "p50_ms": self.latency_percentiles((50,))[50],
+            "p50_ms": pct[50],
+            "p99_ms": pct[99],
             "device": str(self.device) if self.device is not None else "default",
             "backend": self.backend,
             "hops_per_step": self.hops_per_step,

@@ -60,6 +60,7 @@ import bisect
 import dataclasses
 import hashlib
 import itertools
+import logging
 import time
 from collections import deque
 from typing import Container, Deque, Dict, Hashable, List, Optional, Sequence, Tuple
@@ -91,6 +92,8 @@ Pytree = dict
 # lost_session_ids is diagnostics for clients, not an unbounded ledger: the
 # deque keeps the MOST RECENT losses and evicts the oldest beyond this bound
 MAX_LOST_IDS_TRACKED = 1024
+
+log = logging.getLogger(__name__)
 
 
 def _max_capacity(pool) -> int:
@@ -477,6 +480,24 @@ class ShardedSessionPool:
                 shrink_patience=m["shrink_patience"], **kw,
             )
         return SessionPool(placed, self.cfg, m["capacity"], **kw)
+
+    def prewarm(self) -> None:
+        """Compile every step shape the fleet can dispatch, now.
+
+        Each live shard runs its steps once on a masked-out dummy state
+        (``SessionPool.prewarm``): every lane count of its adaptive
+        controller's K ladder, or ``hops_per_step`` alone, at every elastic
+        tier. A compile error raises here, before any session exists,
+        instead of reaching ``pump_all``'s shard-failure handlers as a dead
+        shard.
+        """
+        for i, pool in self._live():
+            sched = self._scheds[i]
+            ks = (
+                sched.config.k_ladder if sched is not None
+                else (self._mk["hops_per_step"],)
+            )
+            pool.prewarm(ks)
 
     def _live(self) -> List[Tuple[int, object]]:
         """(index, pool) for every shard that is up."""
@@ -973,6 +994,7 @@ class ShardedSessionPool:
     def _pump_failure(self, shard: int, *, force: bool = False) -> None:
         """A live shard raised mid-pump: record; kill + re-home when the
         breaker trips (always, with no ``breaker_threshold``)."""
+        log.warning("shard %d failed mid-pump", shard, exc_info=True)
         self._pump_failures[shard] += 1
         if self._shard_failure(shard, force=force):
             self._failover(shard)
@@ -1237,7 +1259,8 @@ class ShardedSessionPool:
             if i in self._dead:
                 s = {
                     "capacity": 0, "active": 0, "free": 0, "hops": 0,
-                    "backlog_hops": 0, "p50_ms": 0.0, "device": "down",
+                    "backlog_hops": 0, "p50_ms": 0.0, "p99_ms": 0.0,
+                    "device": "down",
                     "backend": self._mk["backend"],
                     "hops_per_step": self._mk["hops_per_step"],
                     "alive": False,

@@ -1,16 +1,6 @@
 import jax
 import pytest
 
-# Property tests want hypothesis (a dev extra). The bare container has no
-# network/pip, so fall back to the deterministic in-repo shim there; CI and
-# dev machines (`pip install -e .[dev]`) get the real thing.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro._compat import hypothesis_fallback
-
-    hypothesis_fallback.install()
-
 # Tests run on the single real CPU device (the dry-run is the ONLY place that
 # forces 512 placeholder devices, via its own XLA_FLAGS header — do not set
 # device-count flags here).

@@ -294,8 +294,8 @@ def test_prewarm_compiles_and_serves():
     ref.feed(r, aud)
     ref.pump()
     want = ref.detach(r)
-    ep = ElasticSessionPool(PARAMS, CFG, TIERS, prewarm=True,
-                            step_fn=shared_step("xla"))
+    ep = ElasticSessionPool(PARAMS, CFG, TIERS, step_fn=shared_step("xla"))
+    ep.prewarm()
     s = ep.attach()
     ep.feed(s, aud)
     ep.pump()

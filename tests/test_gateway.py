@@ -427,3 +427,48 @@ def test_gateway_orphan_ttl_reaps():
         c2.close()
     finally:
         g.stop()
+
+
+def test_gateway_stops_with_client_still_connected():
+    """Python >= 3.12's ``Server.wait_closed()`` waits for every connection:
+    ``stop()`` must close open clients itself instead of hanging."""
+    sp = ShardedSessionPool(PARAMS, CFG, 4, shards=2)
+    g = GatewayThread(sp, pump_interval=0.002, call_timeout=10.0)
+    c = GatewayClient(*g.address, reconnect=False)
+    c.attach("lingering")
+    c.feed(_audio(3, 2))
+    t0 = time.monotonic()
+    g.stop()
+    assert time.monotonic() - t0 < 5.0
+    assert not g._thread.is_alive()
+    with pytest.raises((ConnectionError, OSError)):
+        c.read()
+    c.close()
+
+
+class _PumpBoom(RuntimeError):
+    pass
+
+
+def test_gateway_dead_heartbeat_fails_requests_and_stop():
+    """A heartbeat that raises is not silent: requests get its error at
+    once (no client waits out its deadline) and ``stop()`` re-raises it."""
+    sp = ShardedSessionPool(PARAMS, CFG, 4, shards=2)
+    g = GatewayThread(sp, pump_interval=0.002, call_timeout=10.0)
+    c = GatewayClient(*g.address, timeout=10.0)
+    c.attach("victim")
+
+    def boom():
+        raise _PumpBoom("device step failed")
+
+    g.call(lambda p: setattr(p, "pump_all", boom))
+    t0 = time.monotonic()
+    with pytest.raises(SessionError, match="device step failed"):
+        c.feed(_audio(4, 1))
+    with pytest.raises(SessionError, match="device step failed"):
+        c.read()
+    assert time.monotonic() - t0 < 5.0
+    with pytest.raises(_PumpBoom):
+        g.stop()
+    assert not g._thread.is_alive()
+    c.close()

@@ -280,3 +280,38 @@ def test_shard_stats_counters():
     assert sum(s["backlog_hops"] for s in stats) == 0
     assert sum(s["hops"] for s in stats) == 4
     pool.detach(h)
+
+
+@pytest.mark.parametrize(
+    "kw",
+    [
+        dict(),
+        dict(hops_per_step=4),
+        dict(hops_per_step=4, adaptive=True),
+        dict(hops_per_step=2, ingest_ring=4, finite_guard=True),
+        dict(tiers=(2, 4)),
+    ],
+    ids=["k1", "k4", "adaptive", "ring-guard", "elastic"],
+)
+def test_prewarm_leaves_nothing_to_compile(kw):
+    """After ``prewarm()`` serving compiles nothing: every jitted program a
+    shard dispatches already has its executable for the shapes it sees."""
+    from repro.serve import session_server
+
+    pool = ShardedSessionPool(PARAMS, CFG, 4, shards=2, **kw)
+    pool.prewarm()
+    jitted = [session_server._finite_slots, session_server._ring_write]
+    steps = pool._shared[jax.local_devices()[0]][1]
+    before = dict(steps)
+    sizes = [f._cache_size() for f in jitted + list(before.values())]
+
+    handles = [pool.attach(f"s{i}") for i in range(3)]
+    for i, h in enumerate(handles):
+        pool.feed(h, _audio(i, 9)[: 9 * HOP - 5])
+    pool.pump_all()
+    for h in handles:
+        pool.read(h)
+        pool.detach(h)
+
+    assert steps == before
+    assert [f._cache_size() for f in jitted + list(before.values())] == sizes

@@ -25,13 +25,7 @@ import jax
 import numpy as np
 import pytest
 
-try:  # under pytest, conftest installs the fallback; cover `python tests/...`
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # pragma: no cover
-    from repro._compat import hypothesis_fallback
-
-    hypothesis_fallback.install()
-    from hypothesis import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.quant import FP10, quantize
 from repro.models import tftnn as tft
