@@ -1,0 +1,1 @@
+from readers import hops_per_step as read  # noqa: F401

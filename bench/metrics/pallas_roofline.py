@@ -1,0 +1,5 @@
+from readers import roofline_pct
+
+
+def read(ctx):
+    return roofline_pct(ctx)

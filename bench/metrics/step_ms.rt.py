@@ -1,0 +1,1 @@
+from readers import step_ms as read  # noqa: F401
