@@ -1,0 +1,5 @@
+from program import hop_part_ms
+
+
+def read(ctx):
+    return hop_part_ms("server")

@@ -88,5 +88,6 @@ def dilated_split_conv_pallas(
         out_specs=pl.BlockSpec((1, F, C), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, F, C), x.dtype),
         interpret=interpret,
+        name="dilated_split_conv_pallas",
     )(xpad, w, b)
     return out

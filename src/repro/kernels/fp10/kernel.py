@@ -60,5 +60,6 @@ def fp10_quantize_pallas(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows_pad, lanes), dtype),
         interpret=interpret,
+        name="fp10_quantize_pallas",
     )(padded)
     return out.reshape(-1)[:n].reshape(shape)

@@ -130,6 +130,7 @@ def linear_attention_causal_pallas(
         out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
+        name="linear_attention_causal_pallas",
     )(qf, kf, vf)
     return out.reshape(B, H, L, D)
 
@@ -171,6 +172,7 @@ def linear_attention_step_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
+        name="linear_attention_step_pallas",
     )(qf, kf, vf, kvf)
     return out.reshape(B, H, L, D), kv_out.reshape(B, H, D, D)
 
@@ -200,5 +202,6 @@ def linear_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
+        name="linear_attention_pallas",
     )(qf, kf, vf)
     return out.reshape(B, H, L, D)

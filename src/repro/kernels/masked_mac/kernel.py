@@ -73,5 +73,6 @@ def masked_matmul_pallas(
         out_specs=pl.BlockSpec((block_m, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
+        name="masked_matmul_pallas",
     )(x, w, b)
     return out

@@ -285,12 +285,17 @@ def serve_gateway(args) -> None:
     Binds ``--host``/``--port`` and serves the framed streaming protocol
     (see ``repro.serve.gateway``) until interrupted: attach / feed jittery
     chunks / read / detach from any process, with shard health checks and
-    wire-ticket failover running on every pump tick.
+    wire-ticket failover running on every pump tick. ``--trace`` records
+    the serving spans and per-hop timeline (``repro.serve.obs``) from the
+    start; STATS reports their percentiles.
     """
     import asyncio
 
-    from repro.serve.gateway import StreamingGateway
+    from repro.serve import obs
+    from repro.serve.gateway import StreamingGateway, new_event_loop
 
+    if args.trace:
+        obs.enable()
     cfg, params = model(args)
     pool = build_sharded_pool(args, params, cfg)
     gateway = StreamingGateway(pool, host=args.host, port=args.port)
@@ -307,7 +312,7 @@ def serve_gateway(args) -> None:
             await gateway.stop()
 
     try:
-        asyncio.run(_serve())
+        asyncio.run(_serve(), loop_factory=new_event_loop)
     except KeyboardInterrupt:
         print("\n" + pool.report())
 
@@ -406,6 +411,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="gateway task: bind address")
     ap.add_argument("--port", type=int, default=7861,
                     help="gateway task: TCP port (0 picks a free one)")
+    ap.add_argument("--trace", action="store_true",
+                    help="gateway task: record spans and the per-hop "
+                    "timeline (repro.serve.obs); STATS reports them")
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--batch", type=int, default=1)

@@ -449,28 +449,34 @@ def stream_step(
 
     Exactness: with kt=1 all convs are frame-local; the sub-band stage is
     frame-local; only the full-band uni-directional GRU carries state.
+    Each stage runs under a ``jax.named_scope`` (``encoder``, ``subband``,
+    ``fullband``, ``mask_decoder``) that the device trace's op names carry.
     """
     B = frame_ri.shape[0]
     x = frame_ri[:, :, None, :]  # (B, F, 1, 2)
     new_p = dict(p)
-    enc = _encode(cfg, p, new_p, x[:, : cfg.freq_bins], train=False)
-    # transformer trunk, streaming variant
-    Bq, Fp, _, C = enc.shape
-    z = nn.dense(p["att_in"], enc[:, :, 0, :])  # (B, F', d)
+    with jax.named_scope("encoder"):
+        enc = _encode(cfg, p, new_p, x[:, : cfg.freq_bins], train=False)
+        # transformer trunk, streaming variant
+        Bq, Fp, _, C = enc.shape
+        z = nn.dense(p["att_in"], enc[:, :, 0, :])  # (B, F', d)
     new_state = dict(state)
     for i, blk in enumerate(p["blocks"]):
-        zs, _ = _apply_stage(cfg, blk["sub"], z, _sub_cfg(cfg), train=False)
-        zf = zs.reshape(B * Fp, cfg.att_dim)
-        h0 = state[f"block{i}"].reshape(B * Fp, cfg.gru_hidden)
-        h, z_out = streaming_gru_substep(blk["full"], _full_cfg(cfg), h0, zf)
-        new_state[f"block{i}"] = h.reshape(B, Fp, cfg.gru_hidden)
-        z = z_out.reshape(B, Fp, cfg.att_dim)
-    tr = nn.dense(p["att_out"], z)[:, :, None, :]
-    mask = _mask_and_decode(cfg, p, new_p, enc, tr, train=False)  # (B, F, 1, 2)
-    mask = mask[:, :, 0, :]
-    F_in = frame_ri.shape[1]
-    if F_in > cfg.freq_bins:
-        mask = jnp.concatenate([mask, jnp.zeros_like(frame_ri[:, cfg.freq_bins :])], axis=1)
+        with jax.named_scope("subband"):
+            zs, _ = _apply_stage(cfg, blk["sub"], z, _sub_cfg(cfg), train=False)
+        with jax.named_scope("fullband"):
+            zf = zs.reshape(B * Fp, cfg.att_dim)
+            h0 = state[f"block{i}"].reshape(B * Fp, cfg.gru_hidden)
+            h, z_out = streaming_gru_substep(blk["full"], _full_cfg(cfg), h0, zf)
+            new_state[f"block{i}"] = h.reshape(B, Fp, cfg.gru_hidden)
+            z = z_out.reshape(B, Fp, cfg.att_dim)
+    with jax.named_scope("mask_decoder"):
+        tr = nn.dense(p["att_out"], z)[:, :, None, :]
+        mask = _mask_and_decode(cfg, p, new_p, enc, tr, train=False)  # (B, F, 1, 2)
+        mask = mask[:, :, 0, :]
+        F_in = frame_ri.shape[1]
+        if F_in > cfg.freq_bins:
+            mask = jnp.concatenate([mask, jnp.zeros_like(frame_ri[:, cfg.freq_bins :])], axis=1)
     return new_state, mask
 
 

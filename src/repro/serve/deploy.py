@@ -378,43 +378,45 @@ def fused_stream_step(
     B = frame_ri.shape[0]
     x = frame_ri[:, : cfg.freq_bins]  # (B, F, 2), nyquist cropped
 
-    # encoder: conv -> relu (BN folded), dilated block, strided conv -> relu
-    y = nn.relu(_conv_f(dp["enc_in"], x))
-    y = _dilated_fused(plan, dp["enc_dilated"], y)
-    enc = nn.relu(_conv_f(dp["enc_down"], y, stride=cfg.downsample))  # (B, Fp, C)
+    with jax.named_scope("encoder"):
+        # conv -> relu (BN folded), dilated block, strided conv -> relu
+        y = nn.relu(_conv_f(dp["enc_in"], x))
+        y = _dilated_fused(plan, dp["enc_dilated"], y)
+        enc = nn.relu(_conv_f(dp["enc_down"], y, stride=cfg.downsample))  # (B, Fp, C)
+        z = _mm(plan, "att_in", enc)  # (B, Fp, d)
 
     # transformer trunk (streaming): sub-band stage + full-band GRU step
-    z = _mm(plan, "att_in", enc)  # (B, Fp, d)
     Fp = z.shape[1]
     new_state = dict(state)
     for i, blk in enumerate(dp["blocks"]):
-        z = _sub_stage_fused(plan, blk["sub"], z)
-        zf = z.reshape(B * Fp, cfg.att_dim)
-        h0 = state[f"block{i}"].reshape(B * Fp, cfg.gru_hidden)
-        h, g = nn.gru_step(blk["full"]["gru_f"], h0, zf)  # BN2 folded into wi/bi
-        z_out = zf + nn.dense(blk["full"]["w_out"], g)
-        new_state[f"block{i}"] = h.reshape(B, Fp, cfg.gru_hidden)
-        z = z_out.reshape(B, Fp, cfg.att_dim)
-    tr = _mm(plan, "att_out", z)  # (B, Fp, C)
+        with jax.named_scope("subband"):
+            z = _sub_stage_fused(plan, blk["sub"], z)
+        with jax.named_scope("fullband"):
+            zf = z.reshape(B * Fp, cfg.att_dim)
+            h0 = state[f"block{i}"].reshape(B * Fp, cfg.gru_hidden)
+            h, g = nn.gru_step(blk["full"]["gru_f"], h0, zf)  # BN2 folded into wi/bi
+            z_out = zf + nn.dense(blk["full"]["w_out"], g)
+            new_state[f"block{i}"] = h.reshape(B, Fp, cfg.gru_hidden)
+            z = z_out.reshape(B, Fp, cfg.att_dim)
 
-    # mask module (gateless): two pruned 1x1 matmuls around ReLU
-    m = nn.relu(_mm(plan, "mask_conv1", tr))
-    m = _mm(plan, "mask_conv2", m)
-    hfeat = enc * m
-
-    # decoder: dilated block, up-conv -> relu (BN folded), sub-pixel, out conv
-    hfeat = _dilated_fused(plan, dp["dec_dilated"], hfeat)
-    hfeat = nn.relu(_conv_f(dp["dec_up"], hfeat))
-    Bh, Fph, Cr = hfeat.shape
-    r = cfg.downsample
-    hfeat = hfeat.reshape(Bh, Fph, r, Cr // r).reshape(Bh, Fph * r, Cr // r)
-    mask = _conv_f(dp["dec_out"], hfeat)  # (B, F, 2)
-
-    F_in = frame_ri.shape[1]
-    if F_in > cfg.freq_bins:
-        mask = jnp.concatenate(
-            [mask, jnp.zeros_like(frame_ri[:, cfg.freq_bins :])], axis=1
-        )
+    with jax.named_scope("mask_decoder"):
+        tr = _mm(plan, "att_out", z)  # (B, Fp, C)
+        # mask module (gateless): two pruned 1x1 matmuls around ReLU
+        m = nn.relu(_mm(plan, "mask_conv1", tr))
+        m = _mm(plan, "mask_conv2", m)
+        hfeat = enc * m
+        # decoder: dilated block, up-conv -> relu (BN folded), sub-pixel, out conv
+        hfeat = _dilated_fused(plan, dp["dec_dilated"], hfeat)
+        hfeat = nn.relu(_conv_f(dp["dec_up"], hfeat))
+        Bh, Fph, Cr = hfeat.shape
+        r = cfg.downsample
+        hfeat = hfeat.reshape(Bh, Fph, r, Cr // r).reshape(Bh, Fph * r, Cr // r)
+        mask = _conv_f(dp["dec_out"], hfeat)  # (B, F, 2)
+        F_in = frame_ri.shape[1]
+        if F_in > cfg.freq_bins:
+            mask = jnp.concatenate(
+                [mask, jnp.zeros_like(frame_ri[:, cfg.freq_bins :])], axis=1
+            )
     return new_state, mask
 
 
@@ -438,8 +440,10 @@ def stream_hop_fused(
     scan carry — and ``benchmarks/deploy_parity.py`` scans it over whole
     utterances.
     """
-    analysis, frame_ri = hop_analysis(state, hop_samples, plan.cfg, plan.quant)
+    with jax.named_scope("analysis"):
+        analysis, frame_ri = hop_analysis(state, hop_samples, plan.cfg, plan.quant)
     model_state, mask = fused_stream_step(plan, state.model, frame_ri)
-    if plan.quant is not None:
-        mask = quantize(mask, plan.quant)
-    return hop_synthesis(state, analysis, frame_ri, mask, model_state, plan.cfg)
+    with jax.named_scope("synthesis"):
+        if plan.quant is not None:
+            mask = quantize(mask, plan.quant)
+        return hop_synthesis(state, analysis, frame_ri, mask, model_state, plan.cfg)

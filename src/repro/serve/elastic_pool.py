@@ -56,6 +56,7 @@ from repro.models import tftnn as tft_mod
 from repro.serve.faults import FaultPlan
 from repro.serve.scheduler import SchedulerDecision, SchedulerObservation
 from repro.serve.session_server import (
+    STEP_COUNTERS,
     PoolFullError,
     QuarantineRecord,
     Session,
@@ -413,6 +414,8 @@ class ElasticSessionPool:
         ]
         new = self._make_pool(new_capacity)
         new.step_seconds = old.step_seconds  # latency continuity (same list)
+        for k in STEP_COUNTERS:  # so are the step counters
+            setattr(new, k, getattr(old, k))
         new.set_brownout(self._brownout_level)
         self._brownout_hops_base += old.brownout_hops
         for handle, ticket in tickets:

@@ -62,6 +62,7 @@ import concurrent.futures
 import json
 import logging
 import random
+import selectors
 import socket
 import struct
 import threading
@@ -70,8 +71,10 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.serve import obs
 from repro.serve.faults import FaultPlan
 from repro.serve.session_server import (
+    STEP_COUNTERS,
     PoolFullError,
     SessionError,
     SessionPoisonedError,
@@ -94,6 +97,10 @@ MSG_POISONED = 0x86  # session quarantined: JSON {message, good_*} payload
 MSG_AUDIO_DEGRADED = 0x87  # READ reply containing brownout passthrough audio
 MSG_ERROR = 0xFF
 
+_FRAME_SPANS = {
+    MSG_ATTACH: "frame.attach", MSG_FEED: "frame.feed", MSG_READ: "frame.read",
+    MSG_DETACH: "frame.detach", MSG_STATS: "frame.stats",
+}
 _HEADER = struct.Struct("<IB")
 _BUSY_HEAD = struct.Struct("<I")
 # one frame must hold minutes of fp32 audio but never an accidental gigabyte
@@ -134,6 +141,23 @@ async def _read_frame(reader: asyncio.StreamReader) -> Tuple[int, bytes]:
     if length > MAX_FRAME_BYTES:
         raise ProtocolError(f"frame payload {length} exceeds {MAX_FRAME_BYTES}")
     return msg_type, await reader.readexactly(length)
+
+
+class WaitSelector(selectors.DefaultSelector):
+    """The event loop's selector, timing each wait for I/O or a timer as a
+    ``loop_wait`` span while ``obs`` records: the gateway idle, waiting for
+    traffic. A poll (``timeout=0``, callbacks already ready) is no wait."""
+
+    def select(self, timeout=None):
+        if timeout == 0 or not obs.recording():
+            return super().select(timeout)
+        with obs.span("loop_wait"):
+            return super().select(timeout)
+
+
+def new_event_loop() -> asyncio.AbstractEventLoop:
+    """An asyncio loop whose waits show as ``loop_wait`` spans."""
+    return asyncio.SelectorEventLoop(WaitSelector())
 
 
 class StreamingGateway:
@@ -197,8 +221,9 @@ class StreamingGateway:
         self._handles: Dict[str, object] = {}
         # session id -> ticks since its connection dropped (un-detached)
         self._orphans: Dict[str, int] = {}
-        self.pump_ticks = 0
-        self.connections_served = 0
+        self.pump_ticks = 0  # heartbeat and FEED ticks
+        self.feed_ticks = 0  # ticks run by a FEED
+        self.idle_ticks = 0  # ticks whose pump stepped nothing
         self.orphans_reaped = 0
         self.load_shed = 0  # ATTACHes answered with MSG_BUSY
         self.frames_rejected = 0  # unsyncable frames that dropped a connection
@@ -269,28 +294,32 @@ class StreamingGateway:
 
     # -- the serving heartbeat ---------------------------------------------
 
-    def _tick(self) -> None:
+    def _tick(self, feed: bool = False) -> None:
         """One heartbeat: health-probe shards, pump, reap expired orphans.
+        ``feed``: the tick a FEED runs, not the timer's.
 
         A heartbeat that raises kills the gateway: the error is logged once
         and kept, every later request is answered with it, and ``stop()``
         re-raises it.
         """
         try:
-            self._beat()
+            with obs.span("tick.feed" if feed else "tick.heartbeat"):
+                self._beat(feed)
         except Exception as e:
             if self._pump_error is None:
                 log.exception("gateway heartbeat failed; failing every request")
                 self._pump_error = e
             raise
 
-    def _beat(self) -> None:
+    def _beat(self, feed: bool) -> None:
         check = getattr(self.pool, "check_shards", None)
         if check is not None:
             check()
         pump = getattr(self.pool, "pump_all", None) or self.pool.pump
-        pump()
+        stepped = pump()
         self.pump_ticks += 1
+        self.feed_ticks += feed
+        self.idle_ticks += not stepped
         if self.orphan_ttl is None:
             return
         for sid in list(self._orphans):
@@ -330,7 +359,6 @@ class StreamingGateway:
     async def _handle_connection(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
-        self.connections_served += 1
         self._connections[writer] = asyncio.current_task()
         sid: Optional[str] = None
         try:
@@ -350,37 +378,10 @@ class StreamingGateway:
                     except (ConnectionResetError, BrokenPipeError):
                         pass
                     break
-                if self._faults is not None:
-                    # injected hostile client: mangle the frame pre-parse
-                    msg_type, payload = self._faults.corrupt_frame(
-                        msg_type, payload
-                    )
-                try:
-                    reply = self._dispatch_msg(msg_type, payload, sid)
-                    sid = reply[2]
-                    writer.write(_frame(reply[0], reply[1]))
-                except SessionPoisonedError as e:
-                    # the session was quarantined: a typed frame with the
-                    # rollback point, and the connection is unbound so the
-                    # client can re-ATTACH (rolling back via durability)
-                    self.sessions_poisoned += 1
-                    if sid is not None:
-                        self._handles.pop(sid, None)
-                        self._orphans.pop(sid, None)
-                        sid = None
-                    body = json.dumps(
-                        {
-                            "message": str(e),
-                            "good_hops": e.good_hops,
-                            "good_samples_in": e.good_samples_in,
-                        }
-                    ).encode("utf-8")
-                    writer.write(_frame(MSG_POISONED, body))
-                except (SessionError, ProtocolError, ValueError) as e:
-                    if sid is not None and sid not in self._handles:
-                        sid = None  # session lost to a shard failure: unbind
-                        # so this very connection can ATTACH a fresh stream
-                    writer.write(_frame(MSG_ERROR, str(e).encode("utf-8")))
+                # the span ends with the reply written, before any await:
+                # frames of other connections never nest inside it
+                with obs.span(_FRAME_SPANS.get(msg_type, "frame.other"), sid):
+                    sid = self._serve_frame(writer, msg_type, payload, sid)
                 await writer.drain()
         except ConnectionError:
             pass  # client vanished mid-reply, or stop() closed the socket
@@ -393,6 +394,42 @@ class StreamingGateway:
                 await writer.wait_closed()
             except (ConnectionResetError, BrokenPipeError):
                 pass
+
+    def _serve_frame(
+        self, writer: asyncio.StreamWriter, msg_type: int, payload: bytes,
+        sid: Optional[str],
+    ) -> Optional[str]:
+        """Answer one frame; returns the connection's session id after it."""
+        if self._faults is not None:
+            # injected hostile client: mangle the frame pre-parse
+            msg_type, payload = self._faults.corrupt_frame(msg_type, payload)
+        try:
+            reply = self._dispatch_msg(msg_type, payload, sid)
+            sid = reply[2]
+            writer.write(_frame(reply[0], reply[1]))
+        except SessionPoisonedError as e:
+            # the session was quarantined: a typed frame with the
+            # rollback point, and the connection is unbound so the
+            # client can re-ATTACH (rolling back via durability)
+            self.sessions_poisoned += 1
+            if sid is not None:
+                self._handles.pop(sid, None)
+                self._orphans.pop(sid, None)
+                sid = None
+            body = json.dumps(
+                {
+                    "message": str(e),
+                    "good_hops": e.good_hops,
+                    "good_samples_in": e.good_samples_in,
+                }
+            ).encode("utf-8")
+            writer.write(_frame(MSG_POISONED, body))
+        except (SessionError, ProtocolError, ValueError) as e:
+            if sid is not None and sid not in self._handles:
+                sid = None  # session lost to a shard failure: unbind
+                # so this very connection can ATTACH a fresh stream
+            writer.write(_frame(MSG_ERROR, str(e).encode("utf-8")))
+        return sid
 
     def _dispatch_msg(
         self, msg_type: int, payload: bytes, sid: Optional[str]
@@ -456,6 +493,7 @@ class StreamingGateway:
                     for s, msg in getattr(self.pool, "recovery_errors", [])
                 ],
             }
+            stats["trace"] = self._trace_stats(stats["shards"])
             sched_stats = getattr(self.pool, "scheduler_stats", None)
             if sched_stats is not None:
                 scheds = sched_stats()
@@ -478,7 +516,7 @@ class StreamingGateway:
             # opportunistic pump: a whole queued hop is served NOW instead
             # of waiting out the heartbeat interval
             try:
-                self._tick()
+                self._tick(feed=True)
             except Exception as e:
                 raise SessionError(f"gateway heartbeat failed: {e!r}") from e
             return MSG_AUDIO, b"", sid
@@ -499,6 +537,20 @@ class StreamingGateway:
             self._orphans.pop(sid, None)
             return MSG_DETACHED, np.asarray(tail, np.float32).tobytes(), None
         raise ProtocolError(f"unknown message type {msg_type}")
+
+    def _trace_stats(self, shards) -> Dict[str, object]:
+        """STATS' ``trace``: tick counts by reason, each shard's step
+        counters (zero on a dead shard), and a summary of what ``obs``
+        keeps."""
+        return {
+            "ticks": {
+                "feed": self.feed_ticks,
+                "heartbeat": self.pump_ticks - self.feed_ticks,
+                "idle": self.idle_ticks,
+            },
+            "steps": [{k: s.get(k, 0) for k in STEP_COUNTERS} for s in shards],
+            **obs.summary(),
+        }
 
     def _guarded(self, sid: str, op, handle, *args):
         """Run a pool op; a stale handle re-binds through ``pool.lookup``
@@ -554,7 +606,7 @@ class GatewayThread:
             raise ValueError("call_timeout must be > 0")
         self.call_timeout = float(call_timeout)
         self.gateway = (gateway_cls or StreamingGateway)(pool, **gateway_kwargs)
-        self._loop = asyncio.new_event_loop()
+        self._loop = new_event_loop()
         self._started = threading.Event()
         self._startup_error: Optional[BaseException] = None
         self._thread = threading.Thread(
@@ -719,7 +771,6 @@ class GatewayClient:
         self._sock: Optional[socket.socket] = None
         self.session_id: Optional[str] = None
         self.reconnects = 0  # successful re-connections (observability)
-        self.busy_retries = 0  # BUSY frames waited out (retry_busy mode)
         self.last_degraded = False  # last read() carried brownout audio
         self._connect(time.monotonic() + self._timeout)
 
@@ -824,7 +875,6 @@ class GatewayClient:
                     raise
                 time.sleep(delay)
                 busy += 1
-                self.busy_retries += 1
             except TimeoutError:
                 raise  # the per-request deadline is final: no blind retry
             except (ConnectionError, OSError):

@@ -59,6 +59,7 @@ import numpy as np
 
 from repro.core.quant import QuantSpec
 from repro.models import tftnn as tft_mod
+from repro.serve import obs
 from repro.serve.faults import FaultPlan, InjectedFaultError
 from repro.serve.scheduler import SchedulerObservation
 from repro.serve.streaming_se import (
@@ -69,6 +70,9 @@ from repro.serve.streaming_se import (
 )
 
 Pytree = dict
+# per-pool step counters: steps launched, hops they carried, and the lanes
+# they computed (capacity x lane count, whether live or masked)
+STEP_COUNTERS = ("steps", "hops_stepped", "lanes_offered")
 
 
 @jax.jit
@@ -256,6 +260,7 @@ class _Pending:
     counts: np.ndarray  # (B,) int — hops consumed per slot by this step
     t0: float
     dt: Optional[float] = None  # dispatch->ready, set by wait_ready()
+    ready: float = 0.0  # when the output was ready, set with dt
     finite: Optional[jax.Array] = None  # (B,) bool finite-guard verdict
     degraded: bool = False  # produced in brownout passthrough mode
 
@@ -591,6 +596,8 @@ class SessionPool:
         self._pending: List[_Pending] = []
         self._last_ready_t = 0.0  # when the previous step's output was ready
         self.step_seconds: List[float] = []  # pool-wide per-step latency
+        self.steps = self.hops_stepped = self.lanes_offered = 0
+        self._ledger = obs.HopLedger(capacity)  # per-hop stamps while recording
 
     def _step_for(self, k: int, passthrough: bool = False):
         """The compiled step for a ``dispatch(max_hops=k)`` call.
@@ -703,6 +710,7 @@ class SessionPool:
         self._out[slot] = []
         self._parked[slot] = False
         self._degraded_unread[slot] = False
+        self._ledger.clear(slot)
         if self._ring_depth is not None:
             # cursors only: the step masks lanes by hop_counts, so stale
             # device-ring contents from the previous tenant are never read
@@ -772,11 +780,15 @@ class SessionPool:
         if did is not None:
             snapshot_due = self._durability.record_feed(did, arr, self.cfg.hop)
         self._rings[sess.slot].push(arr)
+        hops_before = sess.stats.samples_in // self.cfg.hop
         sess.stats.samples_in += arr.size
         # device-resident ingestion: ship every completed hop immediately so
         # dispatch() finds the backlog already on-device (sub-hop remainders
         # stay host-side until the next feed completes them)
         self._fill_ring(sess.slot)
+        if obs.recording():
+            self._ledger.fed(sess.slot, hops_before,
+                             sess.stats.samples_in // self.cfg.hop)
         if snapshot_due:
             self._durability.snapshot(did, self.snapshot_session(sess))
 
@@ -801,6 +813,7 @@ class SessionPool:
         self._check(sess)  # collect may have quarantined this very session
         chunks = self._out[sess.slot]
         self._out[sess.slot] = []
+        self._ledger.read(sess.slot)
         self._degraded_unread[sess.slot] = False  # queue drained below
         # a parked slot is always below the bound here: collect() above
         # drained the pipeline and the queue was just popped, so unread == 0
@@ -844,10 +857,11 @@ class SessionPool:
         n = min(len(ring) // hop, R - int(self._ring_count[slot]))
         if n <= 0:
             return
-        block = np.zeros((R, hop), np.float32)
-        block[:n] = ring.pop(n * hop).reshape(n, hop)
-        start = (int(self._ring_start[slot]) + int(self._ring_count[slot])) % R
-        self._ring_arr = _ring_write(self._ring_arr, slot, start, block, n)
+        with obs.span("ring_write"):
+            block = np.zeros((R, hop), np.float32)
+            block[:n] = ring.pop(n * hop).reshape(n, hop)
+            start = (int(self._ring_start[slot]) + int(self._ring_count[slot])) % R
+            self._ring_arr = _ring_write(self._ring_arr, slot, start, block, n)
         self._ring_count[slot] += n
 
     def _backlog_hops(self, slot: int) -> int:
@@ -883,6 +897,7 @@ class SessionPool:
             capacity=self.capacity,
         )
 
+    @obs.spanned("dispatch")
     def dispatch(self, max_hops: Optional[int] = None) -> int:
         """Launch ONE batched (multi-)hop step without waiting for its result.
 
@@ -1013,6 +1028,9 @@ class SessionPool:
         step = self._step_for(k, passthrough=brownout >= 3)
         if brownout:
             self.brownout_hops += n_hops
+        self.steps += 1
+        self.hops_stepped += n_hops
+        self.lanes_offered += self.capacity * k
         t0 = time.perf_counter()
         if use_ring:
             if self.device is not None:
@@ -1090,8 +1108,9 @@ class SessionPool:
         jax.block_until_ready(pending.out)
         t = time.perf_counter()
         pending.dt = t - max(pending.t0, self._last_ready_t)
-        self._last_ready_t = t
+        pending.ready = self._last_ready_t = t
 
+    @obs.spanned("wait_ready")
     def wait_ready(self) -> None:
         """Block until every in-flight step's output is ready (no accounting).
 
@@ -1114,13 +1133,20 @@ class SessionPool:
             return 0
         pending = self._pending.pop(0)
         self._mark_ready(pending)
-        out = np.asarray(pending.out)
-        # the finite-guard verdict is a (B,) bool computed on-device at
-        # dispatch time; materializing it here amortizes the readback into
-        # the output transfer the collect already pays for
-        finite = None if pending.finite is None else np.asarray(pending.finite)
+        with obs.span("readback"):
+            out = np.asarray(pending.out)
+            # the finite-guard verdict is a (B,) bool computed on-device at
+            # dispatch time; materializing it here amortizes the readback
+            # into the output transfer the collect already pays for
+            finite = None if pending.finite is None else np.asarray(pending.finite)
         self.step_seconds.append(pending.dt)
+        with obs.span("deliver"):
+            return self._deliver(pending, out, finite, proc_share)
 
+    def _deliver(self, pending: _Pending, out: np.ndarray,
+                 finite: Optional[np.ndarray], proc_share: Optional[float]) -> int:
+        """Queue one read-back step's hops for their sessions; returns its
+        hop count."""
         n_hops = int(pending.counts.sum())
         max_c = int(pending.counts.max())
         # lane-occupancy cost split: a fused dispatch's wall time scales
@@ -1134,6 +1160,7 @@ class SessionPool:
         total = pending.dt if proc_share is None else proc_share * n_hops
         lane_occ = [int((pending.counts > j).sum()) for j in range(max_c)]
         lane_cost = total / max_c if max_c else 0.0
+        stamped = [] if obs.recording() else None
         for slot in np.flatnonzero(pending.counts):
             c = int(pending.counts[slot])
             sess = self._slot_session[slot]
@@ -1155,10 +1182,17 @@ class SessionPool:
                 self._out[slot].append(out[slot, :c].reshape(-1))
             else:
                 self._out[slot].append(out[slot])
+            if stamped is not None:
+                stamped.append((slot, sess.stats.hops, c))
             sess.stats.hops += c
             sess.stats.proc_seconds += lane_cost * sum(
                 1.0 / lane_occ[j] for j in range(c)
             )
+        if stamped:
+            now = time.perf_counter_ns()
+            for slot, before, c in stamped:
+                self._ledger.delivered(slot, before, c, int(pending.t0 * 1e9),
+                                       int(pending.ready * 1e9), now)
         return n_hops
 
     def _quarantine(self, sess: Session) -> None:
@@ -1286,9 +1320,12 @@ class SessionPool:
             flight). Safe to call at any time; idempotent until the next
             ``dispatch()``.
         """
+        if not self._pending:
+            return 0
         total = 0
-        while self._pending:
-            total += self._collect_one(proc_share)
+        with obs.span("collect"):
+            while self._pending:
+                total += self._collect_one(proc_share)
         return total
 
     def step(self) -> int:
@@ -1413,6 +1450,7 @@ class SessionPool:
             "quarantined": self.quarantined_count,
             "brownout": self._brownout,
             "brownout_hops": self.brownout_hops,
+            **{k: getattr(self, k) for k in STEP_COUNTERS},
         }
         prune = self._prune_summary()
         if prune is not None:

@@ -70,6 +70,7 @@ import numpy as np
 
 from repro.core.quant import QuantSpec
 from repro.models import tftnn as tft_mod
+from repro.serve import obs
 from repro.serve.durability import DurabilityError, recover_session
 from repro.serve.elastic_pool import ElasticSessionPool
 from repro.serve.faults import FaultPlan
@@ -700,6 +701,7 @@ class ShardedSessionPool:
 
     # -- audio I/O ----------------------------------------------------------
 
+    @obs.spanned("feed")
     def feed(self, sess, samples) -> None:
         """Queue raw audio on the session's shard (any chunk length)."""
         handle = self._resolve(sess)
@@ -717,6 +719,7 @@ class ShardedSessionPool:
             return
         self._pools[handle.shard].feed(handle.inner, samples)
 
+    @obs.spanned("read")
     def read(self, sess) -> np.ndarray:
         """Pop all enhanced audio produced for this session so far."""
         handle = self._resolve(sess)
@@ -730,6 +733,7 @@ class ShardedSessionPool:
 
     # -- the overlapped hop loop --------------------------------------------
 
+    @obs.spanned("pump_all")
     def pump_all(self) -> int:
         """Pump every shard until no session anywhere has a full hop queued.
 
@@ -1139,6 +1143,7 @@ class ShardedSessionPool:
             if setter is not None:
                 setter(level)
 
+    @obs.spanned("read")
     def read_degraded(self, sess) -> Tuple[np.ndarray, bool]:
         """``read`` plus the brownout passthrough flag for the popped audio
         (True only when brownout level 3 produced any of it)."""

@@ -211,12 +211,17 @@ def stream_hop(
         ``(new_state, out)`` where ``out`` is (B, hop) enhanced audio. Every
         emitted sample is final (COLA normalization by the running ``wsum``
         — no lookahead, exact from the first warm-up hop).
+
+    The step's stages carry ``jax.named_scope`` names: ``analysis``, the
+    model's (``tftnn.stream_step``) and ``synthesis``.
     """
-    analysis, frame_ri = hop_analysis(state, hop_samples, cfg, quant)
+    with jax.named_scope("analysis"):
+        analysis, frame_ri = hop_analysis(state, hop_samples, cfg, quant)
     model_state, mask = tft_mod.stream_step(params, state.model, frame_ri, cfg)
-    if quant is not None:
-        mask = quantize(mask, quant)
-    return hop_synthesis(state, analysis, frame_ri, mask, model_state, cfg)
+    with jax.named_scope("synthesis"):
+        if quant is not None:
+            mask = quantize(mask, quant)
+        return hop_synthesis(state, analysis, frame_ri, mask, model_state, cfg)
 
 
 def make_stream_hop(

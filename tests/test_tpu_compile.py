@@ -13,6 +13,8 @@ only one process may load the TPU library, and every test worker imports
 this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -102,5 +104,10 @@ def test_hop_step_compiles_for_v5e(case, one_chip, model, monkeypatch,
 
     if kw["backend"] == "pallas":
         assert "tpu_custom_call" in text
+        # each kernel keeps its family's name: the device trace's readers
+        # find the kernels by it
+        kernels = set(re.findall(r"%(\w+_pallas)\.\d+ = [^\n]*custom-call\(", text))
+        assert kernels == {"dilated_split_conv_pallas", "masked_matmul_pallas",
+                           "linear_attention_step_pallas"}
     else:
         assert "tpu_custom_call" not in text
