@@ -521,6 +521,11 @@ class ElasticSessionPool:
                 self._durability.record_read(did, handle.stats.samples_out)
         return out
 
+    def backlog_hops(self, handle: ElasticSession) -> int:
+        """Whole hops fed and not yet stepped (see ``SessionPool``)."""
+        self._check(handle)
+        return self._pool.backlog_hops(handle.inner)
+
     def read_degraded(self, handle: ElasticSession) -> Tuple[np.ndarray, bool]:
         """``read`` plus the brownout passthrough flag (see ``SessionPool``)."""
         self._check(handle)

@@ -16,6 +16,13 @@ its stream continues bit-exactly from a live shard (or, when the shard's
 state is truly gone, its next request fails with a ``lost`` error and the
 client re-attaches — bounded loss, never a hang).
 
+Besides the timer, FEEDs drive ticks. A FEED queues its audio and is acked
+at once; the first FEED of an event-loop turn schedules one deferred tick
+(``loop.call_soon``), and later FEEDs of that turn join it, so every
+session fed in the turn shares each batched step. A READ or DETACH of a
+session that still holds whole, unstepped hops runs the pending tick
+first: a read never misses audio its FEED was acked for.
+
 Wire protocol (all integers little-endian): every frame is
 
     u32 payload_length | u8 type | payload
@@ -25,7 +32,7 @@ Client → gateway:
 | type | name | payload |
 |---|---|---|
 | 1 | ATTACH | UTF-8 session id; empty = generate one. Re-attaching an id whose connection dropped ADOPTS the live session (continuation is bit-exact — unread output included) |
-| 2 | FEED | raw float32 samples, any length ≥ 0 |
+| 2 | FEED | raw float32 samples, any length ≥ 0 (acked once queued, before they are stepped) |
 | 3 | READ | — (returns whatever is enhanced so far, possibly empty) |
 | 4 | DETACH | — (returns the unread tail, frees the slot) |
 | 5 | STATS | — (returns the pool's ``shard_stats()`` + failover totals) |
@@ -165,13 +172,15 @@ class StreamingGateway:
 
     Args:
         pool: the ``ShardedSessionPool`` to serve (anything with the sharded
-            surface works: ``attach(session_id)``, feed/read/detach by
-            handle, ``pump_all``; ``check_shards`` is used when present).
+            surface works: ``attach(session_id)``,
+            feed/read/detach/backlog_hops by handle, ``pump_all``;
+            ``check_shards`` is used when present).
         host / port: bind address; port 0 (default) picks a free port —
             read the real one from ``.address`` after ``start()``.
         pump_interval: seconds between heartbeat ticks (health check +
-            ``pump_all``). The tick also runs opportunistically after every
-            FEED, so interactive latency is not bound to the interval.
+            ``pump_all``). FEEDs also run a tick, deferred to the end of
+            their event-loop turn (see the module doc), so interactive
+            latency is not bound to the interval.
         orphan_ttl: pump ticks an orphaned session (connection dropped
             without DETACH) survives awaiting re-attach; ``None`` = forever.
         busy_retry_ms: the retry-after hint carried by ``MSG_BUSY`` when an
@@ -210,6 +219,8 @@ class StreamingGateway:
         self.sessions_poisoned = 0  # MSG_POISONED frames sent
         self._server: Optional[asyncio.AbstractServer] = None
         self._pump_task: Optional[asyncio.Task] = None
+        # the FEED tick scheduled for the end of this event-loop turn
+        self._pending_tick: Optional[asyncio.Handle] = None
         # set when the heartbeat raised: every later request gets it as a
         # typed ERROR (clients fail now, not at their deadline) and stop()
         # re-raises it
@@ -222,8 +233,9 @@ class StreamingGateway:
         # session id -> ticks since its connection dropped (un-detached)
         self._orphans: Dict[str, int] = {}
         self.pump_ticks = 0  # heartbeat and FEED ticks
-        self.feed_ticks = 0  # ticks run by a FEED
+        self.feed_ticks = 0  # ticks run for FEEDs
         self.idle_ticks = 0  # ticks whose pump stepped nothing
+        self.feed_frames = 0  # FEED frames acked
         self.orphans_reaped = 0
         self.load_shed = 0  # ATTACHes answered with MSG_BUSY
         self.frames_rejected = 0  # unsyncable frames that dropped a connection
@@ -271,6 +283,7 @@ class StreamingGateway:
                 completed (a gateway whose heartbeat died must not look
                 like one that served cleanly).
         """
+        self._cancel_pending_tick()
         if self._pump_task is not None:
             self._pump_task.cancel()
             try:
@@ -295,13 +308,16 @@ class StreamingGateway:
     # -- the serving heartbeat ---------------------------------------------
 
     def _tick(self, feed: bool = False) -> None:
-        """One heartbeat: health-probe shards, pump, reap expired orphans.
-        ``feed``: the tick a FEED runs, not the timer's.
+        """One heartbeat: health-probe shards, pump until no whole hop is
+        left, reap expired orphans. ``feed``: the tick FEEDs asked for, run
+        at the end of their event-loop turn or by a READ that needs it; any
+        tick serves them, so it takes the pending one off the loop.
 
         A heartbeat that raises kills the gateway: the error is logged once
         and kept, every later request is answered with it, and ``stop()``
         re-raises it.
         """
+        self._cancel_pending_tick()
         try:
             with obs.span("tick.feed" if feed else "tick.heartbeat"):
                 self._beat(feed)
@@ -338,6 +354,18 @@ class StreamingGateway:
         while True:
             self._tick()
             await asyncio.sleep(self.pump_interval)
+
+    def _cancel_pending_tick(self) -> None:
+        if self._pending_tick is not None:
+            self._pending_tick.cancel()
+            self._pending_tick = None
+
+    def _deferred_tick(self) -> None:
+        """The FEED tick at the end of its event-loop turn."""
+        try:
+            self._tick(feed=True)
+        except Exception:
+            pass  # kept in _pump_error by _tick: later requests get it
 
     # -- per-connection protocol -------------------------------------------
 
@@ -513,13 +541,19 @@ class StreamingGateway:
                 )
             self._guarded(sid, self.pool.feed, handle,
                           np.frombuffer(payload, np.float32))
-            # opportunistic pump: a whole queued hop is served NOW instead
-            # of waiting out the heartbeat interval
-            try:
+            self.feed_frames += 1
+            if self._pending_tick is None:
+                # one tick at the end of this event-loop turn steps every
+                # session fed in it, instead of one tick per FEED
+                self._pending_tick = asyncio.get_running_loop().call_soon(
+                    self._deferred_tick)
+            return MSG_AUDIO, b"", sid
+        if (msg_type in (MSG_READ, MSG_DETACH) and self._pending_tick is not None
+                and self._guarded(sid, self.pool.backlog_hops, handle)):
+            try:  # step the hops this session's FEEDs were acked for
                 self._tick(feed=True)
             except Exception as e:
                 raise SessionError(f"gateway heartbeat failed: {e!r}") from e
-            return MSG_AUDIO, b"", sid
         if msg_type == MSG_READ:
             read_degraded = getattr(self.pool, "read_degraded", None)
             if read_degraded is not None:
@@ -545,6 +579,7 @@ class StreamingGateway:
         return {
             "ticks": {
                 "feed": self.feed_ticks,
+                "feed_frames": self.feed_frames,
                 "heartbeat": self.pump_ticks - self.feed_ticks,
                 "idle": self.idle_ticks,
             },

@@ -833,6 +833,15 @@ class SessionPool:
                 self._durability.record_read(did, sess.stats.samples_out)
         return out
 
+    def backlog_hops(self, sess: Session) -> int:
+        """Whole hops fed to ``sess`` and not yet stepped.
+
+        Raises:
+            SessionError: the handle is not live on this pool.
+        """
+        self._check(sess)
+        return self._backlog_hops(sess.slot)
+
     # -- the batched hop loop ----------------------------------------------
 
     def _unread_hops(self, slot: int) -> int:

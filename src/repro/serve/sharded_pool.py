@@ -731,6 +731,11 @@ class ShardedSessionPool:
             )
         return out
 
+    def backlog_hops(self, sess) -> int:
+        """Whole hops fed to the session and not yet stepped."""
+        handle = self._resolve(sess)
+        return self._pools[handle.shard].backlog_hops(handle.inner)
+
     # -- the overlapped hop loop --------------------------------------------
 
     @obs.spanned("pump_all")

@@ -32,7 +32,9 @@ from repro.serve.gateway import (
     GatewayThread,
     MAX_FRAME_BYTES,
     MSG_ATTACH,
+    MSG_AUDIO,
     MSG_FEED,
+    MSG_READ,
 )
 from chaos import run_chaos_gateway
 
@@ -230,6 +232,94 @@ def test_gateway_chaos_kills_and_drops(gw):
     assert result["kills"] >= 1
     assert result["drops"] >= 2
     assert result["lost"] == set()
+
+
+def _frames_in_one_turn(g, frames):
+    """Serve ``(type, payload, sid)`` frames in one event-loop turn (one
+    ``call`` on the gateway thread); returns their reply payloads."""
+
+    def serve(_pool):
+        replies = []
+        for msg_type, payload, sid in frames:
+            rtype, body, _ = g.gateway._dispatch_msg(msg_type, payload, sid)
+            assert rtype in (MSG_AUDIO, 0x81)  # AUDIO, or ATTACHED
+            replies.append(body)
+        return replies
+
+    return g.call(serve)
+
+
+def _tick_counts(g):
+    gw = g.gateway
+    return g.call(lambda pool: (gw.feed_frames, gw.feed_ticks,
+                                [s["steps"] for s in pool.shard_stats()]))
+
+
+def test_feeds_of_one_turn_share_one_tick():
+    """FEEDs of N sessions handled in one event-loop turn are stepped by
+    one deferred tick: each shard steps ceil(max hops / K) times."""
+    k = 2
+    # two slots a shard, as _reference's pool has; the ids route two a shard
+    sp = ShardedSessionPool(PARAMS, CFG, 2, shards=2, hops_per_step=k)
+    # a heartbeat that never fires during the test: only FEEDs tick
+    g = GatewayThread(sp, pump_interval=3600.0)
+    try:
+        hops = {"s0": 3, "s1": 6, "s2": 5, "s3": 2}
+        audio = {sid: _audio(40 + i, n) for i, (sid, n) in enumerate(hops.items())}
+        _frames_in_one_turn(
+            g, [(MSG_ATTACH, sid.encode(), None) for sid in hops])
+        frames0, ticks0, steps0 = _tick_counts(g)
+        _frames_in_one_turn(
+            g, [(MSG_FEED, audio[sid].tobytes(), sid) for sid in hops])
+        frames1, ticks1, steps1 = _tick_counts(g)
+        assert frames1 - frames0 == len(hops)
+        assert ticks1 - ticks0 == 1
+        assert (frames1 - ticks1) - (frames0 - ticks0) == len(hops) - 1
+        shard_of = g.call(lambda p: {sid: p.lookup(sid).shard for sid in hops})
+        assert sorted(shard_of.values()) == [0, 0, 1, 1]
+        for i, (s0, s1) in enumerate(zip(steps0, steps1)):
+            mine = [n for sid, n in hops.items() if shard_of[sid] == i]
+            assert s1 - s0 == -(-max(mine) // k)
+        outs = _frames_in_one_turn(
+            g, [(MSG_READ, b"", sid) for sid in hops])
+        for sid, body in zip(hops, outs):
+            got = np.frombuffer(body, np.float32)
+            assert got.size == hops[sid] * HOP
+            assert np.array_equal(got, _reference(audio[sid]))
+    finally:
+        g.stop()
+
+
+def test_read_before_deferred_tick_gets_its_hops():
+    """A READ handled before the turn's deferred tick runs it first (its
+    session holds whole, unstepped hops) and gets every hop, bit-exact; a
+    READ of a session with no backlog leaves the tick pending."""
+    sp = ShardedSessionPool(PARAMS, CFG, 2, shards=2)
+    g = GatewayThread(sp, pump_interval=3600.0)
+    try:
+        audio = _audio(50, 4)
+        _frames_in_one_turn(
+            g, [(MSG_ATTACH, b"fed", None), (MSG_ATTACH, b"idle", None)])
+        _, ticks0, _ = _tick_counts(g)
+
+        def feed_then_read(_pool):
+            gw = g.gateway
+            gw._dispatch_msg(MSG_FEED, audio.tobytes(), "fed")
+            _, idle_out, _ = gw._dispatch_msg(MSG_READ, b"", "idle")
+            pending_after_idle_read = gw._pending_tick is not None
+            _, out, _ = gw._dispatch_msg(MSG_READ, b"", "fed")
+            return idle_out, pending_after_idle_read, out, gw._pending_tick
+
+        idle_out, pending, out, left = g.call(feed_then_read)
+        assert idle_out == b"" and pending
+        assert left is None  # the READ ran the tick; none is left to run
+        got = np.frombuffer(out, np.float32)
+        assert got.size == audio.size
+        assert np.array_equal(got, _reference(audio))
+        _, ticks1, _ = _tick_counts(g)
+        assert ticks1 - ticks0 == 1
+    finally:
+        g.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -452,9 +542,12 @@ class _PumpBoom(RuntimeError):
 
 def test_gateway_dead_heartbeat_fails_requests_and_stop():
     """A heartbeat that raises is not silent: requests get its error at
-    once (no client waits out its deadline) and ``stop()`` re-raises it."""
+    once (no client waits out its deadline) and ``stop()`` re-raises it.
+    The failing tick is the one a FEED asked for: the FEED itself is acked
+    as queued, and the first request after it gets the error."""
     sp = ShardedSessionPool(PARAMS, CFG, 4, shards=2)
-    g = GatewayThread(sp, pump_interval=0.002, call_timeout=10.0)
+    # the timer's first tick runs at start, before the fault; no other
+    g = GatewayThread(sp, pump_interval=3600.0, call_timeout=10.0)
     c = GatewayClient(*g.address, timeout=10.0)
     c.attach("victim")
 
@@ -463,8 +556,7 @@ def test_gateway_dead_heartbeat_fails_requests_and_stop():
 
     g.call(lambda p: setattr(p, "pump_all", boom))
     t0 = time.monotonic()
-    with pytest.raises(SessionError, match="device step failed"):
-        c.feed(_audio(4, 1))
+    c.feed(_audio(4, 1))  # acked once queued; its deferred tick fails
     with pytest.raises(SessionError, match="device step failed"):
         c.read()
     assert time.monotonic() - t0 < 5.0
