@@ -4,20 +4,30 @@
 (``models/tftnn.py:macs_per_frame``), kept here so that a change to the
 program cannot move the yardstick; it counts the model's multiply-adds for
 one 128-sample hop at a configuration's widths (8.81 M at TFTNN's published
-widths). One MAC is two operations.
+widths). The ``tftnn`` family module (``bench/models/tftnn.py``) gives it to
+the harness. One MAC is two operations.
 
 ``kernel_cost`` gives the operations a kernel call needs and the bytes it
 must move through HBM at least (every operand read once, every result
-written once), from the shapes in the call's HLO instruction. A kernel
-family without an operation count here is costed by its bytes alone, which
-still bounds its least time from below.
+written once), from the shapes in the call's HLO instruction. The
+operations come from the kernel family's own file,
+``bench/kernels/<family>.py``, whose ``cost(results, operands)`` counts
+them; a file may also claim other families by a ``matches(family)`` of its
+own (``linear_attention.py`` claims every ``linear_attention*`` family).
+A kernel family without a file is costed by its bytes alone, which still
+bounds its least time from below.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import functools
+from pathlib import Path
+from typing import Callable, Dict, Optional, Tuple
 
+import loader
 from tracing import nbytes
+
+KERNELS = Path(__file__).resolve().parent / "kernels"
 
 
 def macs_per_hop(m: Dict) -> float:
@@ -49,28 +59,21 @@ def macs_per_hop(m: Dict) -> float:
     return float(mac)
 
 
-def flops_per_hop(m: Dict) -> float:
-    return 2.0 * macs_per_hop(m)
-
-
-def _dims(shape) -> Tuple[int, ...]:
-    return shape[1]
+@functools.lru_cache(maxsize=None)
+def _counter(family: str) -> Optional[Callable]:
+    """``cost`` of the kernel file that names ``family``, or None."""
+    own = KERNELS / f"{family}.py"
+    if own.is_file():
+        return loader.load(own).cost
+    for path in sorted(KERNELS.glob("*.py")):
+        mod = loader.load(path)
+        if hasattr(mod, "matches") and mod.matches(family):
+            return mod.cost
+    return None
 
 
 def kernel_cost(family: str, results, operands) -> Tuple[float, int]:
     """(operations, HBM bytes) of one Pallas call, from its shapes."""
     moved = nbytes(results) + nbytes(operands)
-    if family == "dilated_split_conv_pallas":
-        # out (B, F, C); weights (k, C/2, C/2): a k-tap conv on half the channels
-        B, F, _ = _dims(results[0])
-        k, cin, cout = _dims(operands[1])
-        return 2.0 * B * F * k * cin * cout, moved
-    if family == "masked_matmul_pallas":
-        M, K = _dims(operands[0])
-        N = _dims(operands[1])[1]
-        return 2.0 * M * K * N, moved
-    if family.startswith("linear_attention"):
-        # q, k, v (BH, L, D): K^T V then Q (K^T V), two (L, D, D) products
-        BH, L, D = _dims(operands[0])
-        return 4.0 * BH * L * D * D, moved
-    return 0.0, moved
+    count = _counter(family)
+    return (float(count(results, operands)) if count else 0.0), moved

@@ -93,7 +93,7 @@ class Generator:
     def __init__(self, args, mix):
         self.args, self.mix = args, mix
         self.hop = args.hop
-        self.bank = synth.bank(args.seed)
+        self.bank = synth.bank(args.seed, mix["sample_rate"])
         self.plan = traffic.plan(mix, args.seed, self.bank.size)
         self.phase = traffic.seat_phases(mix, args.seed)
         self.records = []

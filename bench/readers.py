@@ -60,7 +60,7 @@ def mfu_pct(ctx) -> Optional[float]:
     n = ctx.summary["hops_delivered_in_window"]
     if not n:
         return None
-    rate = n / window_s(ctx) * flops.flops_per_hop(ctx.model)
+    rate = n / window_s(ctx) * ctx.flops_per_hop
     return 100.0 * rate / (ctx.chips * ctx.peaks["flops_bf16"])
 
 

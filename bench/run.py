@@ -7,13 +7,16 @@ seed, the pool built by the launcher's own ``build_sharded_pool`` from the
 configuration's launcher flags, served by a ``StreamingGateway`` on a
 ``GatewayThread`` in this process, and driven over localhost TCP by
 ``loadgen.py`` in a child process that never loads JAX. After the measured
-window the served audio is compared with the plain reference
-(``reference.py``, ``check.py``).
+window the served audio is compared with the model family's plain
+reference (``check.py``).
 
 Everything cell-specific is found by name: the cell in ``BENCHMARK.json``,
-its configuration in ``bench/configs/<config>.json``, its traffic mix in
-``bench/traffic/<traffic>.json`` and each metric's reader in
-``bench/metrics/<metric>.py``.
+its configuration in ``bench/configs/<config>.json``, the configuration's
+model family (program config, weight shapes, plain reference, MACs a hop)
+in ``bench/models/<family>.py``, its traffic mix in
+``bench/traffic/<traffic>.json``, each metric's reader in
+``bench/metrics/<metric>.py`` and each Pallas kernel's operation count in
+``bench/kernels/<kernel>.py`` (``flops.kernel_cost``).
 
 The last line of stdout is the result, one JSON object; the numbers the
 check compared are the last lines of stderr. Without a TPU, with fewer chips
@@ -30,7 +33,6 @@ T_PROCESS = time.monotonic()  # set-up is timed from here
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -45,6 +47,9 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 
+import loader  # noqa: E402
+
+FAMILY_API = ("program_config", "param_shapes", "enhance", "macs_per_hop", "TINY")
 TRACE_LEAD_S = 1.0  # into the window before the profiler starts
 TRACE_S = 1.0  # traced seconds: the device runs ~1e6 ops/s, all of them traced
 CHILD_GRACE_S = 30.0
@@ -62,7 +67,7 @@ class Context:
     steps: List[List[float]]  # per shard, the step seconds of the window
     pump_ticks: int  # gateway heartbeat ticks in the window
     trace: Optional[dict]  # tracing.reduce() of the traced window
-    model: dict
+    flops_per_hop: float  # the model family's analytic count
     sample_rate: int
     chips: int
     peaks: dict
@@ -74,7 +79,11 @@ def load_json(path: Path) -> dict:
 
 
 def load_cell(name: str):
-    """(benchmark, cell, config, mix) for a cell named in BENCHMARK.json."""
+    """(benchmark, cell, config, mix) for a cell named in BENCHMARK.json.
+
+    Stops with a message where the configuration's model family cannot be
+    found, or its mix and configuration state different sample rates.
+    """
     bench = load_json(ROOT / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -83,7 +92,35 @@ def load_cell(name: str):
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(ROOT / configs[cell["config"]]["file"])
     mix = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
+    load_family(config["family"])  # stops here where the family is not whole
+    if mix["sample_rate"] != config["sample_rate"]:
+        raise SystemExit(
+            f"cell {name!r}: traffic {cell['traffic']!r} is at {mix['sample_rate']} Hz, "
+            f"configuration {cell['config']!r} at {config['sample_rate']} Hz")
     return bench, cell, config, mix
+
+
+def load_family(name: str):
+    """The model family module ``bench/models/<name>.py``, which gives:
+
+    - ``program_config(model)``: the object the launcher's
+      ``build_sharded_pool`` serves, from a configuration's ``model`` dict;
+    - ``param_shapes(cfg)``: the weight tree as shapes, which
+      ``weights.make_params`` fills from the seed;
+    - ``enhance(params, model, waves, grid, precision)``: the plain
+      reference, each wave enhanced as a fresh stream and aligned as the
+      server emits it;
+    - ``macs_per_hop(model)``: the analytic multiply-adds of one hop;
+    - ``TINY``: width cuts of ``model`` that the CPU rehearsal runs at.
+    """
+    path = BENCH / "models" / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"model family {name!r}: no {path.relative_to(ROOT)}")
+    mod = loader.load(path)
+    missing = [a for a in FAMILY_API if not hasattr(mod, a)]
+    if missing:
+        raise SystemExit(f"model family {name!r} ({path.relative_to(ROOT)}) lacks {missing}")
+    return mod
 
 
 def cell_metrics(bench: dict, cell: str, traced: bool) -> List[dict]:
@@ -93,11 +130,7 @@ def cell_metrics(bench: dict, cell: str, traced: bool) -> List[dict]:
 
 
 def reader(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", BENCH / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return loader.load(BENCH / "metrics" / f"{name}.py").read
 
 
 def device_phase(chips: int, require_tpu: bool) -> dict:
@@ -237,12 +270,10 @@ def run(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float,
     import jax
     import numpy as np
 
-    from repro.models import tftnn
     from repro.serve.gateway import GatewayThread
 
     import check
     import peaks as peak_table
-    import reference
     import synth
     import tracing
     import weights
@@ -252,15 +283,16 @@ def run(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float,
     phases = {"jax_ready": time.monotonic() - T_PROCESS}
     compiles = CompileCounter()
     gcs = GcPauses()
+    family = load_family(config["family"])
     model = dict(config["model"])
-    cfg = tftnn.TFTConfig(**{**model, "dilation_rates": tuple(model["dilation_rates"])})
+    cfg = family.program_config(model)
     stated = config["serving"]["matmul_precision"]  # None: the TPU default, one bf16 pass
     old_precision = jax.config.jax_default_matmul_precision
     jax.config.update("jax_default_matmul_precision", program_precision or stated)
     work = Path(tempfile.mkdtemp(prefix="bench-"))
     child = None
     try:
-        params = jax.block_until_ready(weights.make_params(seed, cfg))
+        params = jax.block_until_ready(weights.make_params(seed, family.param_shapes(cfg)))
         phases["weights"] = time.monotonic() - T_PROCESS
         if quant is not None:
             import repro.core.quant as q
@@ -280,7 +312,7 @@ def run(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float,
         child = subprocess.Popen(
             [sys.executable, str(BENCH / "loadgen.py"), "--host", host,
              "--port", str(port), "--traffic", str(work / "mix.json"),
-             "--seed", str(seed), "--seconds", str(seconds), "--hop", str(cfg.hop),
+             "--seed", str(seed), "--seconds", str(seconds), "--hop", str(model["hop"]),
              "--out", str(work)],
             stdout=subprocess.PIPE, text=True,
             env={**os.environ, "JAX_PLATFORMS": "cpu"})
@@ -332,18 +364,19 @@ def run(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float,
             shutil.rmtree(tdir, ignore_errors=True)
         ctx = Context(summary=summary, steps=steps,
                       pump_ticks=c1["pump_ticks"] - c0["pump_ticks"], trace=red,
-                      model=model, sample_rate=config["sample_rate"], chips=chips,
+                      flops_per_hop=2.0 * family.macs_per_hop(model),
+                      sample_rate=config["sample_rate"], chips=chips,
                       peaks=peaks, setup_s=setup_s)
         # the check: every stream against the plain reference
-        bank = synth.bank(seed)
+        bank = synth.bank(seed, mix["sample_rate"])
         audio = np.load(work / "streams.npz")
         served, fed = [], []
         for st in summary["streams"]:
             if not st["refused"]:
                 served.append(audio[f"{st['seat']}.{st['index']}"])
                 fed.append(synth.stream(bank, st["offset"], st["fed"]))
-        ref = reference.enhance(params, model, fed, grid=config.get("reference_grid"),
-                                precision=stated or "default") if fed else []
+        ref = family.enhance(params, model, fed, grid=config.get("reference_grid"),
+                             precision=stated or "default") if fed else []
         numbers = check.compare(served, ref)
         errors = [st["error"] for st in summary["streams"] if st["error"]]
         undelivered = sum(st["undelivered_hops"] for st in summary["streams"])

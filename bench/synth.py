@@ -15,8 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
-SAMPLE_RATE = 8000
-UTTERANCE = 3 * SAMPLE_RATE  # the paper's 3 s training segment
+UTTERANCE_S = 3  # the paper's 3 s training segment
 SNR_DB = 2.5  # the paper's mixing SNR
 
 
@@ -45,13 +44,15 @@ def _noise(rng, n, sr):
     return noise / (np.std(noise) + 1e-6)
 
 
-def bank(seed: int, utterances: int = 32) -> np.ndarray:
-    """``utterances`` 3 s noisy utterances back to back, float32, peak 1 each."""
+def bank(seed: int, sample_rate: int = 8000, utterances: int = 32) -> np.ndarray:
+    """``utterances`` 3 s noisy utterances at ``sample_rate`` back to back,
+    float32, peak 1 each."""
     rng = np.random.default_rng([seed, 0x5E])
+    n = UTTERANCE_S * sample_rate
     out = []
     for _ in range(utterances):
-        clean = _voice(rng, UTTERANCE, SAMPLE_RATE)
-        noise = _noise(rng, UTTERANCE, SAMPLE_RATE)
+        clean = _voice(rng, n, sample_rate)
+        noise = _noise(rng, n, sample_rate)
         scale = np.sqrt(np.mean(clean ** 2) / (np.mean(noise ** 2) * 10 ** (SNR_DB / 10) + 1e-12))
         noisy = clean + scale * noise
         out.append(noisy / (np.max(np.abs(noisy)) + 1e-6))

@@ -15,8 +15,6 @@ BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
 sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 
-TINY = dict(freq_bins=64, channels=16, att_dim=8, num_heads=1, gru_hidden=16,
-            dilation_rates=[1, 2, 4])
 # Two K=4 steps per closed-loop chunk, so a state left unchanged shows; a
 # 64-slot step takes ~0.15 s in interpret mode, one round of 64 seats ~20 s.
 CPU_CHUNK = 1024
@@ -26,16 +24,16 @@ CPU_CHUNK = 1024
 def tiny_cell():
     """(cell, config, mix) of a named cell, cut to a size the CPU can run.
 
-    The model's widths, the streams' lengths and the closed loop's chunks
-    are cut; the mix's seats and slots stay as committed, so the check sees
-    the same slots in use as a run on the chip.
+    The model's widths (its family's ``TINY``), the streams' lengths and the
+    closed loop's chunks are cut; the mix's seats and slots stay as
+    committed, so the check sees the same slots in use as a run on the chip.
     """
     import run
 
     def make(name, **mix_kw):
         _, cell, config, mix = run.load_cell(name)
         config = json.loads(json.dumps(config))
-        config["model"].update(TINY)
+        config["model"].update(run.load_family(config["family"]).TINY)
         mix = dict(mix, warmup_s=0.0, length_median_s=1.5, length_cap_s=3.0,
                    chunk_samples=min(mix["chunk_samples"], CPU_CHUNK), **mix_kw)
         return cell, config, mix

@@ -11,8 +11,10 @@ import numpy as np
 import pytest
 
 import reference
+import run
 import weights
-from conftest import TINY
+
+TFTNN = run.load_family("tftnn")
 
 
 @pytest.mark.parametrize("tiny", [True, False], ids=["tiny", "published"])
@@ -25,9 +27,9 @@ def test_reference_matches_program_offline(tiny):
 
     cfg = tftnn.tftnn_config()
     if tiny:
-        cfg = dataclasses.replace(cfg, **{**TINY, "dilation_rates": (1, 2, 4)})
+        cfg = dataclasses.replace(cfg, **{**TFTNN.TINY, "dilation_rates": (1, 2, 4)})
     model = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
-    params = weights.make_params(2**33 + 5, cfg)
+    params = weights.make_params(2**33 + 5, TFTNN.param_shapes(cfg))
     rng = np.random.default_rng(0)
     waves = [0.3 * rng.standard_normal(n).astype(np.float32) for n in (1000, 4000, 2600)]
     outs = reference.enhance(params, model, waves, batch=4, block_frames=8)
@@ -43,9 +45,9 @@ def test_weights_are_a_function_of_the_seed():
 
     from repro.models import tftnn
 
-    cfg = tftnn.tftnn_config()
-    a, b = weights.make_params(7, cfg), weights.make_params(7, cfg)
-    c = weights.make_params(2**32 + 7, cfg)
+    shapes = TFTNN.param_shapes(tftnn.tftnn_config())
+    a, b = weights.make_params(7, shapes), weights.make_params(7, shapes)
+    c = weights.make_params(2**32 + 7, shapes)
     la, lb, lc = (jax.tree_util.tree_leaves(t) for t in (a, b, c))
     assert all(np.array_equal(x, y) for x, y in zip(la, lb))
     assert not all(np.array_equal(x, y) for x, y in zip(la, lc))

@@ -1,9 +1,10 @@
-"""Random TFTNN weights from the run's seed, made on the device.
+"""Random weights from the run's seed, made on the device.
 
-The tree layout is the one the program serves (``init_tft``'s pytree, read
-as shapes only through ``jax.eval_shape``); every value comes from the seed
-here, in one jitted call, in float32 (the dtype both configurations load;
-the FP10 configuration rounds them itself when it builds its deploy plan).
+The tree layout is the one the program serves, as shapes only (the model
+family's ``param_shapes``, through ``jax.eval_shape``); every value comes
+from the seed here, in one jitted call, in float32 (the dtype the TFTNN
+configurations load; the FP10 configuration rounds them itself when it
+builds its deploy plan).
 
 Batch-norm leaves get non-trivial inference statistics (scale near 1, small
 shift and mean, variance in [0.5, 1.5]) so that folding them into the
@@ -22,8 +23,9 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF), seed >> 32)
 
 
-def make_params(seed: int, cfg):
-    """The weight pytree for ``cfg`` (a ``TFTConfig``), on the default device.
+def make_params(seed: int, shapes):
+    """The weight pytree of ``shapes`` (a tree of ``ShapeDtypeStruct``), on
+    the default device.
 
     One uniform and one normal draw cover every leaf (a program of a few
     ops, quick to compile); each leaf takes its own slice.
@@ -31,9 +33,6 @@ def make_params(seed: int, cfg):
     import jax
     import jax.numpy as jnp
 
-    from repro.models import tftnn
-
-    shapes = jax.eval_shape(lambda k: tftnn.init_tft(k, cfg), jax.random.PRNGKey(0))
     paths, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     sizes = [math.prod(sd.shape) for _, sd in paths]
     total = sum(sizes)
