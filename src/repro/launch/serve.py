@@ -1,11 +1,12 @@
 """Serving launcher: streaming speech enhancement (the paper's deployment).
 
-Loads TFTNN weights (or inits fresh), then enhances audio hop-by-hop with
-16 ms algorithmic latency, reporting per-hop wall time against the real-time
-budget. Other tasks: ``--task pool`` serves many sessions through one
-``SessionPool`` (``--elastic --tiers 4,16,64`` swaps in an
-``ElasticSessionPool`` that grows/shrinks along a pre-compiled capacity
-ladder); ``--task sharded`` runs one pool per device behind the
+Loads the model's weights (or inits fresh), then enhances audio hop-by-hop,
+reporting per-hop wall time against the real-time budget. ``--model`` picks
+the model: ``tftnn`` (the paper's, 8 kHz, the default) or ``fullsubnet``
+(16 kHz, float32 on the xla backend only). Other tasks: ``--task pool``
+serves many sessions through one ``SessionPool`` (``--elastic --tiers
+4,16,64`` swaps in an ``ElasticSessionPool`` that grows/shrinks along a
+pre-compiled capacity ladder); ``--task sharded`` runs one pool per device behind the
 consistent-hash router (``--shards N``, elastic shards with ``--elastic``;
 fake CPU devices with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``); ``--task gateway``
@@ -55,22 +56,28 @@ def device_label() -> str:
 
 
 def reduced_cfg(cfg):
-    """The CPU-demo trunk shared by every serving task's ``--reduced`` flag
-    (and by ``benchmarks/server_throughput.py``): paper front end, small
-    model."""
+    """The CPU-demo size shared by every serving task's ``--reduced`` flag
+    (and by ``benchmarks/server_throughput.py``): the model's front end,
+    small widths."""
+    from repro.models.fullsubnet import FullSubNetConfig
+
+    if isinstance(cfg, FullSubNetConfig):
+        return dataclasses.replace(cfg, fb_hidden=32, sb_hidden=16)
     return dataclasses.replace(cfg, freq_bins=64, channels=16, att_dim=8,
                                num_heads=1, gru_hidden=16, dilation_rates=(1, 2, 4))
 
 
 def model(args):
-    """``(cfg, params)``: the paper's TFTNN (``--reduced``: the CPU-demo
-    trunk) with fresh weights from PRNG key 0 — the repo ships none."""
+    """``(cfg, params)``: the ``--model`` (``--reduced``: its CPU-demo size)
+    with fresh weights from PRNG key 0 — the repo ships none."""
+    from repro.models import fullsubnet as fsn
     from repro.models import tftnn as tft
 
-    cfg = tft.tftnn_config()
+    cfg, init = {"tftnn": (tft.tftnn_config(), tft.init_tft),
+                 "fullsubnet": (fsn.FullSubNetConfig(), fsn.init_params)}[args.model]
     if args.reduced:
         cfg = reduced_cfg(cfg)
-    return cfg, tft.init_tft(jax.random.PRNGKey(0), cfg)
+    return cfg, init(jax.random.PRNGKey(0), cfg)
 
 
 def serve_se(args) -> None:
@@ -106,7 +113,7 @@ def serve_se(args) -> None:
     est = jnp.concatenate(outs, axis=1)
     times = sorted(times)
     p50, p99 = times[len(times) // 2], times[int(len(times) * 0.99)]
-    budget = hop / 8000.0
+    budget = hop / cfg.sample_rate
     print(f"hops={n} p50={p50 * 1e3:.2f}ms p99={p99 * 1e3:.2f}ms budget={budget * 1e3:.1f}ms "
           f"real-time={'YES' if p99 < budget else 'no'} on {device_label()}")
     scores = {k: round(float(v), 3) for k, v in all_metrics(est, clean[:, : est.shape[1]]).items()}
@@ -231,7 +238,9 @@ def build_sharded_pool(args, params, cfg, devices=None):
 
     ``devices`` defaults to every local device. Each shard gets
     ``ceil(batch / shards)`` slots, at least 2 (XLA specializes batch-1
-    steps). A compile error raises here, before any session exists.
+    steps). A compile error raises here, before any session exists, and so
+    does a flag the model cannot serve (``--backend pallas``, ``--quant``
+    or ``--prune-keep`` with FullSubNet).
     """
     from repro.core.quant import FP10
     from repro.serve import ShardedSessionPool
@@ -339,6 +348,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--task", choices=["se", "pool", "sharded", "gateway", "lm"],
                     default="se")
+    ap.add_argument("--model", choices=["tftnn", "fullsubnet"], default="tftnn",
+                    help="se/pool/sharded/gateway tasks: the paper's TFTNN "
+                    "(8 kHz) or FullSubNet (16 kHz, arXiv 2010.15508; float32 "
+                    "on the xla backend only: --backend pallas, --quant and "
+                    "--prune-keep stop with an error)")
     ap.add_argument("--quant", action="store_true",
                     help="pool/sharded tasks: serve on the paper's FP10 grid")
     ap.add_argument("--backend", choices=["xla", "pallas"], default="xla",

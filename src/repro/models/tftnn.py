@@ -40,8 +40,11 @@ from repro.core.bn_transformer import (
     init_bn_transformer,
     streaming_gru_substep,
 )
+from repro.core.quant import QuantSpec, quantize
 
 Params = Dict[str, Any]
+# has a deploy plan (serve/deploy.py): BN folding, Pallas kernels, FP10, pruning
+DEPLOYABLE = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +53,7 @@ class TFTConfig:
 
     name: str = "tftnn"
     # front end
+    sample_rate: int = 8000  # the paper's
     n_fft: int = 512
     hop: int = 128
     freq_bins: int = 256  # 257 rfft bins, nyquist dropped for a pow-2 axis
@@ -478,6 +482,45 @@ def stream_step(
         if F_in > cfg.freq_bins:
             mask = jnp.concatenate([mask, jnp.zeros_like(frame_ri[:, cfg.freq_bins :])], axis=1)
     return new_state, mask
+
+
+def apply_mask(spec_ri: jax.Array, mask: jax.Array) -> jax.Array:
+    """TFTNN's bounded complex-ratio mask: ``2 tanh(mask)`` times the noisy
+    spectrum, both (..., 2) real/imaginary; returns the complex product."""
+    a, b = spec_ri[..., 0], spec_ri[..., 1]
+    m = 2.0 * jnp.tanh(mask)
+    mc, md = m[..., 0], m[..., 1]
+    return (a * mc - b * md) + 1j * (a * md + b * mc)
+
+
+def stream_estimate(
+    p: Params,
+    state: Params,
+    frame_ri: jax.Array,
+    cfg: TFTConfig,
+    quant: Optional[QuantSpec] = None,
+) -> Tuple[Params, jax.Array]:
+    """The serving seam's model step: ``stream_step``, the mask rounded onto
+    ``quant``'s grid when given, applied to the current frame. Returns
+    ``(new_state, est)`` with ``est`` the (B, F) complex spectrum to
+    synthesise."""
+    state, mask = stream_step(p, state, frame_ri, cfg)
+    with jax.named_scope("mask"):
+        if quant is not None:
+            mask = quantize(mask, quant)
+        return state, apply_mask(frame_ri, mask)
+
+
+def passthrough_estimate(state: Params, frame_ri: jax.Array, cfg: TFTConfig):
+    """The brownout hop's spectrum: the current frame, unmasked (TFTNN has no
+    look-ahead); the recurrent state is carried untouched."""
+    return state, frame_ri[..., 0] + 1j * frame_ri[..., 1]
+
+
+def offline_estimate(p: Params, spec_ri: jax.Array, cfg: TFTConfig) -> jax.Array:
+    """(B, F, T, 2) noisy spectrogram -> (B, F, T) complex enhanced spectra."""
+    mask, _ = apply_tft(p, spec_ri, cfg)
+    return apply_mask(spec_ri, mask)
 
 
 # ---------------------------------------------------------------------------

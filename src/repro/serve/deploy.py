@@ -443,7 +443,9 @@ def stream_hop_fused(
     with jax.named_scope("analysis"):
         analysis, frame_ri = hop_analysis(state, hop_samples, plan.cfg, plan.quant)
     model_state, mask = fused_stream_step(plan, state.model, frame_ri)
-    with jax.named_scope("synthesis"):
+    with jax.named_scope("mask"):
         if plan.quant is not None:
             mask = quantize(mask, plan.quant)
-        return hop_synthesis(state, analysis, frame_ri, mask, model_state, plan.cfg)
+        est = tft_mod.apply_mask(frame_ri, mask)
+    with jax.named_scope("synthesis"):
+        return hop_synthesis(state, analysis, est, model_state, plan.cfg)

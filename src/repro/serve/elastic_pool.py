@@ -52,7 +52,6 @@ import jax
 import numpy as np
 
 from repro.core.quant import QuantSpec
-from repro.models import tftnn as tft_mod
 from repro.serve.faults import FaultPlan
 from repro.serve.scheduler import SchedulerDecision, SchedulerObservation
 from repro.serve.session_server import (
@@ -65,6 +64,7 @@ from repro.serve.session_server import (
     SessionPool,
     SessionTicket,
 )
+from repro.serve.streaming_se import ModelConfig
 
 Pytree = dict
 
@@ -107,7 +107,7 @@ class ElasticSessionPool:
         cfg: model/front-end config shared by every tier.
         tiers: strictly increasing capacity ladder, e.g. ``(4, 16, 64)``.
             The pool starts at ``tiers[0]`` and never exceeds ``tiers[-1]``.
-        quant / sample_rate / donate / device / backend / prune_keep /
+        quant / donate / device / backend / prune_keep /
             prune_axis / prune_granularity / prune_block / inflight /
             max_unread_hops / on_unparked /
             hops_per_step: forwarded to every tier's ``SessionPool`` (see
@@ -153,11 +153,10 @@ class ElasticSessionPool:
     def __init__(
         self,
         params: Pytree,
-        cfg: tft_mod.TFTConfig,
+        cfg: ModelConfig,
         tiers: Sequence[int] = (4, 16, 64),
         *,
         quant: Optional[QuantSpec] = None,
-        sample_rate: int = 8000,
         donate: bool = True,
         device: Optional[jax.Device] = None,
         backend: str = "xla",
@@ -202,7 +201,6 @@ class ElasticSessionPool:
         self.quant = quant
         self.backend = backend
         self.device = device
-        self._sample_rate = sample_rate
         self._donate = donate
         self._inflight = inflight
         self._max_unread_hops = max_unread_hops
@@ -266,7 +264,6 @@ class ElasticSessionPool:
             self.cfg,
             capacity,
             quant=self.quant,
-            sample_rate=self._sample_rate,
             donate=self._donate,
             device=self.device,
             backend=self.backend,
@@ -317,7 +314,7 @@ class ElasticSessionPool:
 
     @property
     def sample_rate(self) -> int:
-        return self._sample_rate
+        return self.cfg.sample_rate
 
     @property
     def step_seconds(self) -> List[float]:

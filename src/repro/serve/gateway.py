@@ -486,8 +486,9 @@ class StreamingGateway:
                 return MSG_BUSY, body, None
             return MSG_ATTACHED, sid.encode("utf-8"), sid
         if msg_type == MSG_STATS:
+            shards = self.pool.shard_stats()
             stats = {
-                "shards": self.pool.shard_stats(),
+                "shards": shards,
                 "dead_shards": getattr(self.pool, "dead_shards", []),
                 "sessions_failed_over": getattr(
                     self.pool, "sessions_failed_over", 0
@@ -498,6 +499,8 @@ class StreamingGateway:
                 ],
                 "pump_ticks": self.pump_ticks,
                 "active": self.pool.num_active,
+                "state_bytes_per_slot": max(
+                    (s.get("state_bytes_per_slot", 0) for s in shards), default=0),
                 "orphans": len(self._orphans),
                 "load_shed": self.load_shed,
                 "frames_rejected": self.frames_rejected,

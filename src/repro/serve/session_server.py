@@ -58,11 +58,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quant import QuantSpec
-from repro.models import tftnn as tft_mod
 from repro.serve import obs
 from repro.serve.faults import FaultPlan, InjectedFaultError
 from repro.serve.scheduler import SchedulerObservation
 from repro.serve.streaming_se import (
+    ModelConfig,
     StreamState,
     init_stream,
     make_stream_hop,
@@ -128,7 +128,7 @@ def _ring_write(ring, slot, start, block, n):
     return ring.at[slot, idx].set(jnp.where(live, block, cur))
 
 
-def warm_inputs(cfg: tft_mod.TFTConfig, capacity: int, k: int,
+def warm_inputs(cfg: ModelConfig, capacity: int, k: int,
                 ring_depth: Optional[int]) -> Tuple[np.ndarray, ...]:
     """Host inputs (after the state) for one all-masked-out dispatch of the
     ``max_hops=k`` step at ``capacity`` slots: what ``prewarm`` runs so jit
@@ -339,14 +339,13 @@ class SessionPool:
         pool.detach(s)
 
     Args:
-        params: TFTNN parameter pytree (weights are quantized once here when
+        params: model parameter pytree (weights are quantized once here when
             ``quant`` is set).
         cfg: model/front-end config; ``cfg.hop`` fixes the step granularity.
         capacity: number of slots. Baked into the compiled step — churn never
             changes it, only a new pool can.
         quant: optional ``repro.core.quant`` grid (FP10/FXP8) for the paper's
             deployment number formats.
-        sample_rate: audio sample rate for RTF accounting (paper: 8 kHz).
         donate: donate the recurrent state to the jit step (in-place update).
         device: pin params, state, and per-hop inputs to this ``jax.Device``.
             ``None`` (default) uses JAX's default placement. This is the
@@ -475,11 +474,10 @@ class SessionPool:
     def __init__(
         self,
         params: Pytree,
-        cfg: tft_mod.TFTConfig,
+        cfg: ModelConfig,
         capacity: int,
         *,
         quant: Optional[QuantSpec] = None,
-        sample_rate: int = 8000,
         donate: bool = True,
         device: Optional[jax.Device] = None,
         backend: str = "xla",
@@ -521,7 +519,7 @@ class SessionPool:
             )
         self.cfg = cfg
         self.capacity = capacity
-        self.sample_rate = sample_rate
+        self.sample_rate = cfg.sample_rate  # RTF accounting
         self.quant = quant
         self.device = device
         self.backend = backend
@@ -541,6 +539,9 @@ class SessionPool:
             self._steps.setdefault((hops_per_step, ingest_ring, False), step_fn)
         self._step = self._step_for(hops_per_step)  # default full-K step
         state = init_stream(params, cfg, capacity)
+        # what a ticket, a snapshot and the per-lane masked merge move per slot
+        self.state_bytes_per_slot = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(state)) // capacity
         self._state: StreamState = (
             jax.device_put(state, device) if device is not None else state
         )
@@ -1433,8 +1434,9 @@ class SessionPool:
             ``hops`` (total hops enhanced for currently-live sessions),
             ``backlog_hops`` (full hops queued but not yet processed —
             the pressure signal), ``p50_ms`` (median dispatch→ready step
-            latency), ``p99_ms``, and ``device`` (where this shard's state
-            lives).
+            latency), ``p99_ms``, ``device`` (where this shard's state
+            lives) and ``state_bytes_per_slot`` (one stream's state: what a
+            ticket, a snapshot and the per-lane masked merge move).
             Pruned pools additionally report ``prune``: requested keep,
             exact realized sparsity, and the masked-MAC skip-rate counters
             per masked weight.
@@ -1459,6 +1461,7 @@ class SessionPool:
             "quarantined": self.quarantined_count,
             "brownout": self._brownout,
             "brownout_hops": self.brownout_hops,
+            "state_bytes_per_slot": self.state_bytes_per_slot,
             **{k: getattr(self, k) for k in STEP_COUNTERS},
         }
         prune = self._prune_summary()

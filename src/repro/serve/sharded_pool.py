@@ -69,7 +69,6 @@ import jax
 import numpy as np
 
 from repro.core.quant import QuantSpec
-from repro.models import tftnn as tft_mod
 from repro.serve import obs
 from repro.serve.durability import DurabilityError, recover_session
 from repro.serve.elastic_pool import ElasticSessionPool
@@ -87,6 +86,7 @@ from repro.serve.session_server import (
     SessionPoisonedError,
     SessionPool,
 )
+from repro.serve.streaming_se import ModelConfig
 
 Pytree = dict
 
@@ -235,7 +235,7 @@ class ShardedSessionPool:
             exceed the device count — shards then round-robin over devices,
             which is how CPU tests exercise multi-shard routing on one core.
         devices: explicit device list; defaults to ``jax.local_devices()``.
-        quant / sample_rate / donate: forwarded to every ``SessionPool``.
+        quant / donate: forwarded to every ``SessionPool``.
         backend: hop-step implementation forwarded to every shard — ``"xla"``
             or ``"pallas"`` (the deploy-compiled fused path, see
             ``repro.serve.deploy``). One compiled step per device either way.
@@ -335,13 +335,12 @@ class ShardedSessionPool:
     def __init__(
         self,
         params: Pytree,
-        cfg: tft_mod.TFTConfig,
+        cfg: ModelConfig,
         capacity: int,
         *,
         shards: Optional[int] = None,
         devices: Optional[Sequence[jax.Device]] = None,
         quant: Optional[QuantSpec] = None,
-        sample_rate: int = 8000,
         donate: bool = True,
         backend: str = "xla",
         prune_keep: Optional[float] = None,
@@ -401,7 +400,7 @@ class ShardedSessionPool:
             prune_granularity=prune_granularity, prune_block=prune_block,
             hops_per_step=hops_per_step, capacity=capacity, tiers=tiers,
             shrink_fraction=shrink_fraction, shrink_patience=shrink_patience,
-            sample_rate=sample_rate, inflight=inflight,
+            inflight=inflight,
             max_unread_hops=max_unread_hops, on_unparked=on_unparked,
             ingest_ring=ingest_ring, finite_guard=finite_guard,
         )
@@ -463,7 +462,7 @@ class ShardedSessionPool:
             self._shared[dev] = (jax.device_put(self._params, dev), {})
         placed, step_fns = self._shared[dev]
         kw = dict(
-            quant=m["quant"], sample_rate=m["sample_rate"], donate=m["donate"],
+            quant=m["quant"], donate=m["donate"],
             device=dev, backend=m["backend"], inflight=m["inflight"],
             max_unread_hops=m["max_unread_hops"],
             on_unparked=m["on_unparked"], hops_per_step=m["hops_per_step"],
@@ -524,7 +523,7 @@ class ShardedSessionPool:
 
     @property
     def sample_rate(self) -> int:
-        return self._mk["sample_rate"]
+        return self.cfg.sample_rate
 
     @property
     def dead_shards(self) -> List[int]:

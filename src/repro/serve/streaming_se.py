@@ -1,14 +1,21 @@
 """The paper's streaming speech-enhancement service (Section III-E / IV-A).
 
 Consumes raw audio sample-by-sample (hop-sized chunks), maintains the STFT
-analysis window + the TFTNN recurrent state + the overlap-add synthesis tail,
-and emits enhanced audio with one hop (16 ms) of algorithmic latency — the
-software twin of the ASIC's real-time loop (512-sample window, 128 hop,
-8 kHz).
+analysis window + the model's recurrent state + the overlap-add synthesis
+tail, and emits enhanced audio hop by hop — the software twin of the ASIC's
+real-time loop (TFTNN: 512-sample window, 128 hop, 8 kHz, one hop of
+algorithmic latency).
 
-The synthesis side uses weighted overlap-add with the same Hann window; the
-COLA normalizer for hop = n_fft/4 is constant once 4 windows overlap, so each
-emitted hop is final (no lookahead).
+The model is found from the config's type (``model_module``): TFTNN
+(``repro.models.tftnn``) or FullSubNet (``repro.models.fullsubnet``, 16 kHz,
+hop 256, two frames of look-ahead). Each model's step returns the complex
+spectrum to synthesise: TFTNN its ``2 tanh`` mask times the current frame,
+FullSubNet its cIRM times the frame two hops back, which its state holds.
+
+The synthesis side uses weighted overlap-add with the same Hann window,
+normalised by the running sum of squared windows over the frames that
+reached each sample, so each emitted hop is final for any hop <= n_fft/2
+(75% overlap for TFTNN, 50% for FullSubNet).
 
 One pure batched ``stream_hop`` is the single implementation of the hop math.
 Three consumers share it:
@@ -29,16 +36,34 @@ leading batch axis, so a server can reset or swap individual slots with
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
 from repro.audio.stft import hann
 from repro.core.quant import QuantSpec, quantize, quantize_tree
+from repro.models import fullsubnet as fsn_mod
 from repro.models import tftnn as tft_mod
 
 Pytree = Any
+ModelConfig = Union[tft_mod.TFTConfig, fsn_mod.FullSubNetConfig]
+_MODELS = {tft_mod.TFTConfig: tft_mod, fsn_mod.FullSubNetConfig: fsn_mod}
+
+
+def model_module(cfg: ModelConfig):
+    """The model module that serves ``cfg``, found from the config's type.
+
+    It gives ``init_stream_state``, ``stream_estimate`` (one frame in, the
+    complex spectrum to synthesise out), ``passthrough_estimate`` (the
+    brownout hop's unmasked spectrum, with the model's delay),
+    ``offline_estimate`` (a whole spectrogram) and ``DEPLOYABLE`` (whether
+    it has a deploy plan for Pallas, ``quant`` and pruning).
+    """
+    try:
+        return _MODELS[type(cfg)]
+    except KeyError:
+        raise TypeError(f"no model serves a {type(cfg).__name__}") from None
 
 
 @jax.tree_util.register_dataclass
@@ -47,14 +72,14 @@ class StreamState:
     analysis: jax.Array  # (B, n_fft) rolling input window
     synthesis: jax.Array  # (B, n_fft) overlap-add accumulator
     wsum: jax.Array  # (B, n_fft) per-stream window-square accumulator
-    model: Pytree  # TFTNN recurrent state, leaves (B, ...)
+    model: Pytree  # the model's recurrent state, leaves (B, ...)
 
 
-def init_stream(params: Pytree, cfg: tft_mod.TFTConfig, batch: int) -> StreamState:
+def init_stream(params: Pytree, cfg: ModelConfig, batch: int) -> StreamState:
     """Zeroed streaming state for ``batch`` independent streams.
 
     Args:
-        params: TFTNN parameters (shapes only — used to size the recurrent
+        params: model parameters (shapes only — used to size the recurrent
             model state).
         cfg: model/front-end config (``n_fft`` fixes the window buffers).
         batch: number of streams; the leading axis of every state leaf.
@@ -67,7 +92,7 @@ def init_stream(params: Pytree, cfg: tft_mod.TFTConfig, batch: int) -> StreamSta
         analysis=jnp.zeros((batch, cfg.n_fft)),
         synthesis=jnp.zeros((batch, cfg.n_fft)),
         wsum=jnp.zeros((batch, cfg.n_fft)),
-        model=tft_mod.init_stream_state(params, cfg, batch),
+        model=model_module(cfg).init_stream_state(params, cfg, batch),
     )
 
 
@@ -89,7 +114,7 @@ def reset_slots(state: StreamState, slot_mask: jax.Array) -> StreamState:
 def hop_analysis(
     state: StreamState,
     hop_samples: jax.Array,
-    cfg: tft_mod.TFTConfig,
+    cfg: ModelConfig,
     quant: Optional[QuantSpec] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Front half of the hop: roll the analysis window, window, FFT, quantize.
@@ -113,23 +138,19 @@ def hop_analysis(
 def hop_synthesis(
     state: StreamState,
     analysis: jax.Array,
-    frame_ri: jax.Array,
-    mask: jax.Array,
+    est: jax.Array,
     model_state: Pytree,
-    cfg: tft_mod.TFTConfig,
+    cfg: ModelConfig,
 ) -> Tuple[StreamState, jax.Array]:
-    """Back half of the hop: apply the complex mask, iFFT, weighted OLA.
+    """Back half of the hop: iFFT, weighted OLA.
 
-    Takes the (possibly quantized) mask the model emitted and produces
+    Takes the (B, F) complex spectrum the model step emitted and produces
     ``(new_state, out)`` exactly as documented on ``stream_hop``. Shared by
-    both hop backends — the COLA/wsum invariant lives in ONE place.
+    every hop core (both backends and the brownout passthrough) — the wsum
+    invariant lives in ONE place.
     """
     n_fft, hop = cfg.n_fft, cfg.hop
-    w = hann(n_fft, frame_ri.dtype)
-    a, b = frame_ri[..., 0], frame_ri[..., 1]
-    m = 2.0 * jnp.tanh(mask)
-    mc, md = m[..., 0], m[..., 1]
-    est = (a * mc - b * md) + 1j * (a * md + b * mc)
+    w = hann(n_fft, analysis.dtype)
     y = jnp.fft.irfft(est, n=n_fft, axis=-1) * w
 
     synthesis = state.synthesis + y
@@ -147,44 +168,34 @@ def hop_synthesis(
 def hop_passthrough(
     state: StreamState,
     hop_samples: jax.Array,
-    cfg: tft_mod.TFTConfig,
+    cfg: ModelConfig,
 ) -> Tuple[StreamState, jax.Array]:
     """Model-free identity hop: analysis -> synthesis with a unit mask.
 
     The graceful-brownout floor. Runs the exact analysis front half and the
-    exact weighted-OLA back half of ``stream_hop`` but skips the TFTNN
-    entirely (``est = spec``), so under terminal overload the server keeps
-    emitting *unenhanced* — but real-time, finite, correctly windowed —
-    audio instead of going silent. The model's recurrent state is carried
-    through untouched: when the brownout lifts, enhancement resumes from
-    whatever recurrent context the stream had (same contract as an inactive
-    masked slot).
+    exact weighted-OLA back half of ``stream_hop`` but skips the model
+    entirely (``est`` is the noisy frame), so under terminal overload the
+    server keeps emitting *unenhanced* — but real-time, finite, correctly
+    windowed — audio instead of going silent. The frame is the one the
+    model path would synthesise (``passthrough_estimate``): a model with
+    look-ahead keeps its delay, so the output does not jump when a brownout
+    starts or ends. The model's recurrent state is carried through
+    untouched: when the brownout lifts, enhancement resumes from whatever
+    recurrent context the stream had (same contract as an inactive masked
+    slot).
 
     Signature-compatible with ``stream_hop``'s hop core, so
     ``make_stream_hop(..., passthrough=True)`` reuses the identical
     masking / fused-scan / ingestion-ring plumbing.
     """
-    n_fft, hop = cfg.n_fft, cfg.hop
     analysis, frame_ri = hop_analysis(state, hop_samples, cfg)
-    w = hann(n_fft, frame_ri.dtype)
-    est = frame_ri[..., 0] + 1j * frame_ri[..., 1]
-    y = jnp.fft.irfft(est, n=n_fft, axis=-1) * w
-
-    synthesis = state.synthesis + y
-    wsum = state.wsum + (w * w)[None, :]
-    out = synthesis[:, :hop] / jnp.maximum(wsum[:, :hop], 1e-8)
-    new_state = StreamState(
-        analysis=analysis,
-        synthesis=jnp.concatenate([synthesis[:, hop:], jnp.zeros_like(synthesis[:, :hop])], axis=1),
-        wsum=jnp.concatenate([wsum[:, hop:], jnp.zeros_like(wsum[:, :hop])], axis=1),
-        model=state.model,
-    )
-    return new_state, out
+    model_state, est = model_module(cfg).passthrough_estimate(state.model, frame_ri, cfg)
+    return hop_synthesis(state, analysis, est, model_state, cfg)
 
 
 def stream_hop(
     params: Pytree,
-    cfg: tft_mod.TFTConfig,
+    cfg: ModelConfig,
     state: StreamState,
     hop_samples: jax.Array,  # (B, hop) new audio
     *,
@@ -196,37 +207,41 @@ def stream_hop(
     offline scan, the session server, and the quantized path.
 
     Args:
-        params: TFTNN parameters (pre-quantized by the caller when serving
+        params: model parameters (pre-quantized by the caller when serving
             on a deployment grid).
-        cfg: model/front-end config (``n_fft``, ``hop``).
+        cfg: model/front-end config (``n_fft``, ``hop``); its type picks
+            the model (``model_module``).
         state: per-stream state from ``init_stream`` / a previous call.
         hop_samples: (B, hop) new raw audio, one hop per stream.
         quant: optional ``repro.core.quant`` grid (e.g. FP10 or FXP8):
             additionally rounds the spectral features entering the model and
             the mask leaving it — the activation half of the paper's
-            Table VI deployment format. Weight quantization is the caller's
-            job (``make_stream_hop`` / ``quantize_tree``).
+            Table VI deployment format (TFTNN only). Weight quantization is
+            the caller's job (``make_stream_hop`` / ``quantize_tree``).
 
     Returns:
         ``(new_state, out)`` where ``out`` is (B, hop) enhanced audio. Every
-        emitted sample is final (COLA normalization by the running ``wsum``
-        — no lookahead, exact from the first warm-up hop).
+        emitted sample is final (normalization by the running ``wsum``,
+        exact from the first warm-up hop). Output sample ``i`` is input
+        sample ``i - (n_fft - hop)``, plus ``look_ahead * hop`` for a model
+        with look-ahead.
 
     The step's stages carry ``jax.named_scope`` names: ``analysis``, the
-    model's (``tftnn.stream_step``) and ``synthesis``.
+    model's (``tftnn.stream_step``: ``encoder``, ``subband``, ``fullband``,
+    ``mask_decoder``; ``fullsubnet.stream_step``: ``normalize``,
+    ``fullband``, ``subband``), ``mask`` and ``synthesis``.
     """
     with jax.named_scope("analysis"):
         analysis, frame_ri = hop_analysis(state, hop_samples, cfg, quant)
-    model_state, mask = tft_mod.stream_step(params, state.model, frame_ri, cfg)
+    model_state, est = model_module(cfg).stream_estimate(
+        params, state.model, frame_ri, cfg, quant)
     with jax.named_scope("synthesis"):
-        if quant is not None:
-            mask = quantize(mask, quant)
-        return hop_synthesis(state, analysis, frame_ri, mask, model_state, cfg)
+        return hop_synthesis(state, analysis, est, model_state, cfg)
 
 
 def make_stream_hop(
     params: Pytree,
-    cfg: tft_mod.TFTConfig,
+    cfg: ModelConfig,
     *,
     quant: Optional[QuantSpec] = None,
     donate: bool = True,
@@ -323,6 +338,10 @@ def make_stream_hop(
     masking / fused-scan / ring plumbing. ``quant`` and the pruning knobs
     are ignored (there is no model to quantize or prune) and ``backend``
     only needs to be valid — both backends share the pure-jnp passthrough.
+
+    Pallas, ``quant`` and pruning need a deploy plan: for a model whose
+    module says ``DEPLOYABLE = False`` they raise ``ValueError`` here, when
+    the pool builds its step.
     """
     if max_hops_per_step < 1:
         raise ValueError("max_hops_per_step must be >= 1")
@@ -333,6 +352,14 @@ def make_stream_hop(
         )
     if backend not in ("xla", "pallas"):
         raise ValueError(f"unknown backend {backend!r}: expected 'xla' or 'pallas'")
+    if not passthrough and not model_module(cfg).DEPLOYABLE:
+        asked = [name for name, on in (("backend 'pallas'", backend == "pallas"),
+                                       ("quant", quant is not None),
+                                       ("prune_keep", prune_keep is not None)) if on]
+        if asked:
+            raise ValueError(
+                f"{cfg.name} serves in float32 on the xla backend only: "
+                f"{', '.join(asked)} need(s) a deploy plan, which it has not")
     if passthrough:
         # brownout floor: model-free analysis->synthesis identity hop. No
         # deploy plan, no weight quantization — there is no model to quantize
@@ -419,7 +446,7 @@ def make_stream_hop(
 
 def enhance_streaming(
     params: Pytree,
-    cfg: tft_mod.TFTConfig,
+    cfg: ModelConfig,
     wave: jax.Array,
     *,
     quant: Optional[QuantSpec] = None,
@@ -449,12 +476,14 @@ def enhance_streaming(
     return outs.transpose(1, 0, 2).reshape(B, n * hop)
 
 
-def enhance_offline(params: Pytree, cfg: tft_mod.TFTConfig, wave: jax.Array) -> jax.Array:
+def enhance_offline(params: Pytree, cfg: ModelConfig, wave: jax.Array) -> jax.Array:
     """Offline reference for the streaming loop: framed STFT -> mask -> OLA.
 
     Frames the signal exactly as the hop loop sees it (zero history of
     ``n_fft - hop`` samples, window ending at sample ``(k+1)*hop``), runs the
-    model over the whole utterance at once, and synthesizes by weighted
+    model over the whole utterance at once (``offline_estimate``, which
+    places a look-ahead model's frame ``k - look_ahead`` at ``k`` as the
+    stream does), and synthesizes by weighted
     overlap-add with the squared-window normalizer. Because every window
     covering output region [k*hop, (k+1)*hop) has index <= k, the streaming
     loop's running ``wsum`` equals the full-accumulation normalizer used here
@@ -473,12 +502,7 @@ def enhance_offline(params: Pytree, cfg: tft_mod.TFTConfig, wave: jax.Array) -> 
     spec = jnp.fft.rfft(frames, axis=-1)  # (B, T, F)
     spec_ri = jnp.stack([spec.real, spec.imag], axis=-1).transpose(0, 2, 1, 3)  # (B, F, T, 2)
 
-    mask, _ = tft_mod.apply_tft(params, spec_ri, cfg)
-
-    a, b = spec_ri[..., 0], spec_ri[..., 1]
-    m = 2.0 * jnp.tanh(mask)
-    mc, md = m[..., 0], m[..., 1]
-    est = (a * mc - b * md) + 1j * (a * md + b * mc)  # (B, F, T)
+    est = model_module(cfg).offline_estimate(params, spec_ri, cfg)  # (B, F, T)
     y = jnp.fft.irfft(est.transpose(0, 2, 1), n=n_fft, axis=-1) * w  # (B, T, n_fft)
 
     out_len = n * hop + n_fft
